@@ -45,6 +45,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.faults import FaultEvent, FaultSchedule
 from repro.core.simulator import (
     AdaptiveCase,
@@ -473,6 +474,7 @@ def main(argv: list[str] | None = None):
                     help="run the smallest grid of the selected section "
                          "(default: the disagreement sweep) and exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.section == "run_faults":
         if args.smoke:

@@ -20,6 +20,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.schedule import (
     greedy_matching_schedule,
     oblivious_schedule,
@@ -196,6 +197,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--twohop-timing", action="store_true",
                     help="also run the numpy-vs-jax twohop_table")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     rows = run(n=args.n, horizon=args.horizon, backend=args.backend)
     print("name,us_per_call,derived")

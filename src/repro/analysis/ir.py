@@ -187,7 +187,7 @@ def _analyze(jaxpr) -> _Cost:
     """Walk one ``jax.core.Jaxpr``: flops / bytes / liveness / carries.
 
     Containers recurse: ``scan`` scales its body by trip count and records
-    carry avals; ``pjit``/call-like primitives fold their inner jaxpr once;
+    carry avals; ``jit``/call-like primitives fold their inner jaxpr once;
     ``cond`` takes the max over branches; ``while`` folds cond+body once
     (no static trip count — flagged via ``unknown``).
     """
@@ -320,9 +320,9 @@ def _trace_cost(fn, specs) -> _Cost:
     import jax
     closed = jax.make_jaxpr(fn)(*specs)
     inner = closed
-    # a jitted fn traces to a single pjit equation wrapping the real body
+    # a jitted fn traces to a single jit equation wrapping the real body
     if len(closed.jaxpr.eqns) == 1 \
-            and closed.jaxpr.eqns[0].primitive.name == "pjit":
+            and closed.jaxpr.eqns[0].primitive.name == "jit":
         inner = closed.jaxpr.eqns[0].params["jaxpr"]
     return _analyze(inner.jaxpr)
 

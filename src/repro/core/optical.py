@@ -11,8 +11,10 @@ into collective programs usable inside ``shard_map``:
 * :func:`optical_allreduce` — ring all-reduce whose ring is one of the
   schedule's cyclic matchings.
 
-On CPU these are exercised with ``--xla_force_host_platform_device_count``
-(tests spawn a subprocess); on TPU the same code runs over ICI.
+:func:`run_schedule_demo` runs all three over every device JAX sees: the
+chips of a TPU host over ICI, or fake CPU devices made with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the tests do this in
+a subprocess).
 """
 from __future__ import annotations
 
@@ -20,8 +22,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .schedule import Schedule
 
@@ -125,44 +126,69 @@ def optical_allreduce(x: jax.Array, sched: Schedule, axis_name: str) -> jax.Arra
     return acc
 
 
-def run_schedule_demo(n: int = 8, seed: int = 0) -> dict:
-    """End-to-end demo on n devices: Vermilion-scheduled all-gather,
-    all-reduce, and chunk delivery; verified against dense references.
-    Requires >= n jax devices (set XLA_FLAGS before importing jax)."""
+def _ramp(shape: tuple) -> jax.Array:
+    """Float32 payload whose rows along the last axis all differ, made of
+    integers small enough that sums are exact in any order."""
+    i = jnp.arange(int(np.prod(shape)), dtype=jnp.int32)
+    return (i % 251 + 256 * (i // shape[-1])).astype(
+        jnp.float32).reshape(shape)
+
+
+def _lands_everywhere(out: jax.Array, ref: jax.Array, devs) -> bool:
+    """``out`` has a shard on every device of ``devs`` and each shard equals
+    the reference's shard on that device, bit for bit."""
+    got = {s.device: s for s in out.addressable_shards}
+    want = {s.device: s for s in ref.addressable_shards}
+    if set(got) != set(devs) or set(want) != set(devs):
+        return False
+    return all(got[d].index == want[d].index
+               and np.array_equal(np.asarray(got[d].data),
+                                  np.asarray(want[d].data))
+               for d in devs)
+
+
+def run_schedule_demo(n: int | None = None, seed: int = 0,
+                      row_elems: int = 4) -> dict:
+    """Vermilion-scheduled all-gather, all-reduce and chunk delivery over
+    the first ``n`` devices (default: all of them), each compared with
+    XLA's own collective on the same mesh: ``all_gather``, ``psum`` and a
+    transpose.  Every device holds ``row_elems`` float32 values of each
+    payload.  An entry is True when every device holds the reference's
+    result, bit for bit (the payloads are small integers, so the ring sum
+    is exact in any order)."""
     from .traffic import uniform
     from .schedule import vermilion_schedule
 
-    devs = jax.devices()[:n]
-    if len(devs) < n:
-        raise RuntimeError(f"need {n} devices, have {len(jax.devices())}")
+    devs = jax.devices()
+    n = len(devs) if n is None else n
+    if not 2 <= n <= len(devs):
+        raise RuntimeError(f"run_schedule_demo needs 2 or more devices and "
+                           f"JAX sees {len(devs)} (asked for n={n})")
+    devs = devs[:n]
     mesh = Mesh(np.array(devs), ("pod",))
+    rows = NamedSharding(mesh, P("pod"))
     sched = vermilion_schedule(uniform(n), k=2, d_hat=1, seed=seed)
 
-    x = jnp.arange(n * 4, dtype=jnp.float32).reshape(n, 4)
+    def smap(body, out_specs):
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                                     out_specs=out_specs, check_vma=False))
 
-    ag = shard_map(
-        lambda xs: optical_allgather(xs[0], sched, "pod"),
-        mesh=mesh, in_specs=P("pod", None), out_specs=P(None, None),
-        check_rep=False,
-    )
-    ag_ok = bool(np.allclose(np.asarray(jax.jit(ag)(x)), np.asarray(x)))
+    ramp = jax.jit(_ramp, static_argnums=0, out_shardings=rows)
+    x = ramp((n, row_elems))                    # x[s] is shard s's row
+    ag = smap(lambda xs: optical_allgather(xs[0], sched, "pod"), P())(x)
+    ag_ref = smap(lambda xs: jax.lax.all_gather(xs[0], "pod"), P())(x)
+    ar = smap(lambda xs: optical_allreduce(xs[0], sched, "pod")[None],
+              P("pod"))(x)
+    ar_ref = smap(lambda xs: jax.lax.psum(xs, "pod"), P("pod"))(x)
 
-    ar = shard_map(
-        lambda xs: optical_allreduce(xs[0], sched, "pod")[None],
-        mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None),
-        check_rep=False,
-    )
-    ar_ok = bool(np.allclose(np.asarray(jax.jit(ar)(x)),
-                             np.tile(np.asarray(x).sum(0), (n, 1))))
-
-    # chunk delivery: shard s holds payload matrix rows destined to each v;
-    # after one period shard s's row u == payload that u addressed to s.
-    payload = jnp.arange(n * n, dtype=jnp.float32).reshape(n, n)  # [src, dst]
-    sp = shard_map(
-        lambda p: schedule_permute(p[0], sched, "pod")[None],
-        mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None),
-        check_rep=False,
-    )
-    got = np.asarray(jax.jit(sp)(payload))      # got[s, u] = payload[u, s]
-    sp_ok = bool(np.allclose(got, np.asarray(payload).T))
-    return {"allgather_ok": ag_ok, "allreduce_ok": ar_ok, "permute_ok": sp_ok}
+    # chunk delivery: payload[s, v] is what shard s addresses to v; after
+    # one period shard s's row u == payload[u, s], the transpose
+    chunk = max(row_elems // n, 1)
+    payload = ramp((n, n, chunk))
+    sp = smap(lambda p: schedule_permute(p[0], sched, "pod")[None],
+              P("pod"))(payload)
+    sp_ref = jax.jit(lambda p: jnp.swapaxes(p, 0, 1),
+                     out_shardings=rows)(payload)
+    return {"allgather_ok": _lands_everywhere(ag, ag_ref, devs),
+            "allreduce_ok": _lands_everywhere(ar, ar_ref, devs),
+            "permute_ok": _lands_everywhere(sp, sp_ref, devs)}

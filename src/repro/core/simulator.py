@@ -85,8 +85,9 @@ percentiles (two-hop modes only up to ``_TWOHOP_FCT_MAX_N``).  The jax
 adaptive path replays the control plane host-side (decision-identical to
 numpy — the epoch counters are arrivals-only) and batches every case's
 serving through ONE device scan; configurations needing per-slot host
-decisions inside the serving loop (faults / repair / ``fullest`` /
-jitter) raise ``ValueError`` and stay NumPy-only.  Aggregates match
+decisions inside the serving loop stay NumPy-only: fault injection
+raises ``NotImplementedError``, and repair / ``fullest`` / jitter raise
+``ValueError``.  Aggregates match
 numpy to f32 tolerance (~1e-3 relative); FCTs match exactly on
 well-conditioned instances.
 
@@ -2686,7 +2687,8 @@ def _jax_fns() -> dict:
                            voq / jnp.maximum(queue, _JEPS)[:, :, None], 0.0)
             # dense-by-design small-n kernel (see _TWOHOP_DENSE_MAX_N)
             mvd = jnp.einsum(  # lint: allow-dense
-                "buv,bud->bvd", send_u[:, :, None] * ls, qs)
+                "buv,bud->bvd", send_u[:, :, None] * ls, qs,
+                precision=jax.lax.Precision.HIGHEST)
             voq = jnp.maximum(voq - send_u[:, :, None] * qs, 0.0)
             # bits whose relay node IS the destination arrive at once
             diag = jnp.diagonal(mvd, axis1=1, axis2=2)     # mvd[b, v, v]
@@ -2811,7 +2813,8 @@ def _jax_fns() -> dict:
             frac = jnp.where(RS > _JEPS,
                              send1 / jnp.maximum(RS, _JEPS), 0.0)
             dp = jnp.einsum(  # lint: allow-dense
-                "busv,buv->bsv", R3, frac)
+                "busv,buv->bsv", R3, frac,
+                precision=jax.lax.Precision.HIGHEST)
             R3 = R3 * (1.0 - frac)[:, :, None, :]
             second = send1.sum(axis=(1, 2))
             cap = cap - send1

@@ -164,26 +164,6 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool):
     return fn, args, extra_bytes, mesh
 
 
-def normalize_cost_analysis(cost) -> dict:
-    """Flatten ``Compiled.cost_analysis()`` to one dict.
-
-    Newer JAX returns a list with one flat dict per executable module
-    (older versions returned the dict directly); sum the per-module
-    numbers so ``cost.get("flops")`` keeps working either way."""
-    if cost is None:
-        return {}
-    if isinstance(cost, dict):
-        return cost
-    out: dict = {}
-    for entry in cost:
-        for k, v in (entry or {}).items():
-            if isinstance(v, (int, float)):
-                out[k] = out.get(k, 0.0) + v
-            else:
-                out.setdefault(k, v)
-    return out
-
-
 def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
     import jax
 
@@ -194,7 +174,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
     compiled = lowered.compile()
     t_compile = time.time() - t0 - t_lower
 
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis() or {}
     try:
         mem = compiled.memory_analysis()
         mem_d = {

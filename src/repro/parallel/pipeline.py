@@ -14,7 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -61,11 +60,11 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, mesh: Mesh,
         last = jax.lax.psum(outs * mask, axis)
         return last[None]
 
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     out = f(stage_params, x_microbatches)
     return out[0]
