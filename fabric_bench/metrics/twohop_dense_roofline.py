@@ -1,0 +1,9 @@
+"""Share of the ``twohop_dense`` kernel's roofline, in percent: the least time
+of its counted work (``fabric_bench/kernels/twohop_dense.py``) at the chip's
+published peaks, over its device time in the trace."""
+
+from fabric_bench import roofline
+
+
+def read(ctx):
+    return roofline.share(ctx, "twohop_dense")
