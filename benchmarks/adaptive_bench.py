@@ -12,9 +12,9 @@ Compares four control policies on one phase-shifting websearch workload
                  control loop: great until the first phase shift).
   * oblivious  — round-robin baseline, never recomputed.
 
-Prints the repo's ``name,us_per_call,derived`` CSV plus a ``# summary``
-block checking the headline claims: adaptive beats oblivious, tracks the
-oracle's utilization, and the stale schedule degrades after a shift.
+Prints ``name,derived`` CSV plus a ``# summary`` block checking the
+headline claims: adaptive beats oblivious, tracks the oracle's
+utilization, and the stale schedule degrades after a shift.
 
 ``run_disagreement()`` sweeps gather staleness -> per-node schedule
 disagreement -> utilization (every ToR schedules from its own partial
@@ -271,7 +271,7 @@ def _print_faults(rows: list[AdaptiveRow], check: bool = True) -> None:
     by = {r.label: r for r in rows}
     for row in rows:
         r = row.result
-        print(f"adaptive_faults[{row.label}],{row.sim_s * 1e6:.0f},"
+        print(f"adaptive_faults[{row.label}],"
               f"util={r.utilization:.3f};"
               f"post={_post_fault_util(row):.3f};"
               f"lost={r.fault_lost_bits:.3e};"
@@ -324,7 +324,7 @@ def _print_disagreement(rows: list[AdaptiveRow]) -> None:
     by_steps: dict[int, AdaptiveRow] = {}
     for row in rows:
         r = row.result
-        print(f"adaptive_disagree[{row.label}],{row.sim_s * 1e6:.0f},"
+        print(f"adaptive_disagree[{row.label}],"
               f"util={r.utilization:.3f};"
               f"disagree={np.mean(row.epoch_disagreement):.3f};"
               f"coll_loss={np.mean(row.epoch_collision_loss):.3f};"
@@ -493,7 +493,7 @@ def main(argv: list[str] | None = None):
                                 args.epoch_slots)
 
     by_label = {}
-    print("name,us_per_call,derived")
+    print("name,derived")
     for row in rows:
         by_label[row.label] = row
         r = row.result
@@ -501,7 +501,7 @@ def main(argv: list[str] | None = None):
         tv = row.epoch_estimate_tv
         tv_s = (f"est_tv={np.nanmean(tv):.3f};"
                 if np.isfinite(tv).any() else "")
-        print(f"adaptive[{row.label}],{row.sim_s * 1e6:.0f},"
+        print(f"adaptive[{row.label}],"
               f"util={r.utilization:.3f};"
               f"util_pre={u[list(first)].mean():.3f};"
               f"util_post={u[list(rest)].mean():.3f};"
@@ -528,7 +528,7 @@ def main(argv: list[str] | None = None):
     charged = run_charging()
     for row in charged:
         r = row.result
-        print(f"adaptive_charged[{row.label}],{row.sim_s * 1e6:.0f},"
+        print(f"adaptive_charged[{row.label}],"
               f"util={r.utilization:.3f};stale_slots={row.stale_slots};"
               f"recomputes={row.recomputes};"
               f"constr_ms={row.construction_s * 1e3:.0f}")
@@ -536,7 +536,7 @@ def main(argv: list[str] | None = None):
     tradeoff = run_epoch_tradeoff()
     best_by_p: dict[int, AdaptiveRow] = {}
     for row in tradeoff:
-        print(f"adaptive_tradeoff[{row.label}],{row.sim_s * 1e6:.0f},"
+        print(f"adaptive_tradeoff[{row.label}],"
               f"util={row.result.utilization:.3f};"
               f"dark_slots={row.dark_slots};recomputes={row.recomputes}")
         p = row.meta["penalty"]

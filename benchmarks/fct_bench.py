@@ -82,7 +82,6 @@ def run(n: int = 16, d_hat: int = 4, horizon: int = 4000,
             "util": r.utilization,
             "done": r.completed_frac,
             "hops": r.avg_hops,
-            "us": sr.sim_s * 1e6,
         })
     return rows
 
@@ -200,10 +199,9 @@ def main(argv: list[str] | None = None) -> None:
     enable_compile_cache()
 
     rows = run(n=args.n, horizon=args.horizon, backend=args.backend)
-    print("name,us_per_call,derived")
+    print("name,derived")
     for r in rows:
         print(f"fct_fig5[{r['system']},load={r['load']},{r['backend']}],"
-              f"{r['us']:.0f},"
               f"p99short={r['p99_short']:.0f};p99long={r['p99_long']:.0f};"
               f"util={r['util']:.3f};done={r['done']:.3f};hops={r['hops']:.2f}")
     if not args.no_timing:

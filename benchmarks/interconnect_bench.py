@@ -81,7 +81,7 @@ def run_simulated(horizon: int = 30000) -> list[dict]:
         fct = r.result.fct_slots
         drain = float(fct.max()) * SLOT_S if np.isfinite(fct).all() \
             else float("inf")
-        out.append({"arch": r.label, "t_sim": drain, "us": r.sim_s * 1e6})
+        out.append({"arch": r.label, "t_sim": drain})
     return out
 
 

@@ -1,6 +1,7 @@
 """Benchmark harness: one section per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV.  Sections:
+Prints ``name,us_per_call,derived`` CSV (``name,derived`` for the
+simulated sections, which time nothing).  Sections:
   - throughput_fig7     (Fig 7: throughput across demand matrices)
   - bound_fig8a/b       (Fig 8: convergence to (k-1)/k)
   - fct_fig5            (Fig 5/6: FCT + utilization, websearch)
@@ -54,7 +55,6 @@ def _adaptive_row_json(row) -> dict:
         "excised_planes": row.excised_planes,
         "epoch_utilization": [round(float(u), 6)
                               for u in row.epoch_utilization],
-        "sim_s": row.sim_s,
         "meta": row.meta,
     }
 

@@ -98,7 +98,7 @@ def run_simulated(n: int = 16, d_hat: int = 4, horizon: int = 800,
             SweepCase(so, wl, "single_hop", f"{name}/obl-singlehop"),
         ]
     return [{"label": r.label, "util": r.result.utilization,
-             "done": r.result.completed_frac, "us": r.sim_s * 1e6}
+             "done": r.result.completed_frac}
             for r in run_sweep(cases, BITS_PER_SLOT)]
 
 
@@ -110,8 +110,9 @@ def main(n: int = 16) -> None:
     for r in rows:
         derived = ";".join(f"{c}={r[c]:.3f}" for c in cols[1:])
         print(f"throughput_fig7[{r['demand']},n={n}],{r['us']:.0f},{derived}")
+    print("name,derived")
     for r in run_simulated(n):
-        print(f"throughput_sim[{r['label']},n={n}],{r['us']:.0f},"
+        print(f"throughput_sim[{r['label']},n={n}],"
               f"util={r['util']:.3f};done={r['done']:.3f}")
 
 

@@ -18,6 +18,7 @@ from .matching import (
     extract_perfect_matching,
 )
 from .rounding import round_matrices
+from .tracing import span
 from .traffic import hose_normalize, saturate
 
 __all__ = [
@@ -202,17 +203,18 @@ def vermilion_scaled_demands(
     if k < 2:
         raise ValueError("k >= 2 (k-1 must be positive)")
     pre = []
-    for m in mats:
-        m = np.asarray(m, dtype=np.float64)
-        n = m.shape[0]
-        if normalize == "saturate":
-            norm = saturate(m)
-        elif normalize == "hose":
-            norm = hose_normalize(m)
-        else:
-            raise ValueError(normalize)
-        np.fill_diagonal(norm, 0.0)
-        pre.append((k - 1) * n * norm)
+    with span("fabric.construct.normalize"):
+        for m in mats:
+            m = np.asarray(m, dtype=np.float64)
+            n = m.shape[0]
+            if normalize == "saturate":
+                norm = saturate(m)
+            elif normalize == "hose":
+                norm = hose_normalize(m)
+            else:
+                raise ValueError(normalize)
+            np.fill_diagonal(norm, 0.0)
+            pre.append((k - 1) * n * norm)
     return pre
 
 
@@ -224,8 +226,9 @@ def vermilion_rounded(
     from its scaled demand by < 1 with row/col sums <= (k-1) * n — the
     doubly-substochastic quantization contract Theorem 3 builds on, and
     what :mod:`repro.analysis.certify` checks entrywise."""
-    return round_matrices(vermilion_scaled_demands(mats, k=k,
-                                                   normalize=normalize))
+    pre = vermilion_scaled_demands(mats, k=k, normalize=normalize)
+    with span("fabric.construct.round"):
+        return round_matrices(pre)
 
 
 def vermilion_emulated_topologies(
@@ -326,27 +329,14 @@ def vermilion_schedules(
     construction engine behind :func:`per_node_schedules`, where each
     epoch builds up to n same-shape view schedules at once.
     """
-    es = vermilion_emulated_topologies(mats, k=k, seed=seed,
-                                      normalize=normalize)
-    if method == "euler":
-        same = len({e.shape[0] for e in es}) == 1
-        n = es[0].shape[0] if es else 0
-        shifts = (np.arange(n)[None, :] + np.arange(1, n)[:, None]) % n
-        if same:
-            perms_all = decompose_matchings_euler_batch(es, known=shifts)
-        else:  # pragma: no cover - callers pass same-shape batches
-            perms_all = [
-                decompose_matchings_euler(
-                    e, known=(np.arange(e.shape[0])[None, :]
-                              + np.arange(1, e.shape[0])[:, None])
-                    % e.shape[0])
-                for e in es]
-    elif method == "hk":
-        perms_all = [decompose_matchings(e) for e in es]
-    else:
-        raise ValueError(f"unknown decomposition method {method!r}")
-    if spread:
-        perms_all = [spread_matchings(p) for p in perms_all]
+    with span("fabric.construct", kind="vermilion") as sp:
+        es = vermilion_emulated_topologies(mats, k=k, seed=seed,
+                                          normalize=normalize)
+        sp.attrs.update(count=len(es), n=es[0].shape[0] if es else 0)
+        with span("fabric.construct.decompose"):
+            perms_all = _decompose(es, method)
+        if spread:
+            perms_all = [spread_matchings(p) for p in perms_all]
     return [
         Schedule(
             perms=perms,
@@ -358,6 +348,25 @@ def vermilion_schedules(
         )
         for perms in perms_all
     ]
+
+
+def _decompose(es: list[np.ndarray], method: str) -> list[np.ndarray]:
+    """Each emulated multigraph of ``es`` as its k*n perfect matchings."""
+    if method == "euler":
+        same = len({e.shape[0] for e in es}) == 1
+        n = es[0].shape[0] if es else 0
+        shifts = (np.arange(n)[None, :] + np.arange(1, n)[:, None]) % n
+        if same:
+            return decompose_matchings_euler_batch(es, known=shifts)
+        return [  # pragma: no cover - callers pass same-shape batches
+            decompose_matchings_euler(
+                e, known=(np.arange(e.shape[0])[None, :]
+                          + np.arange(1, e.shape[0])[:, None])
+                % e.shape[0])
+            for e in es]
+    if method == "hk":
+        return [decompose_matchings(e) for e in es]
+    raise ValueError(f"unknown decomposition method {method!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -481,8 +490,9 @@ def oblivious_schedule(
 ) -> Schedule:
     """RotorNet/Sirius-style round-robin over the n-1 cyclic shifts,
     emulating a uniform all-to-all mesh."""
-    shifts = np.arange(1, n)
-    perms = (np.arange(n)[None, :] + shifts[:, None]) % n
+    with span("fabric.construct", kind="oblivious", count=1, n=n):
+        shifts = np.arange(1, n)
+        perms = (np.arange(n)[None, :] + shifts[:, None]) % n
     return Schedule(perms=perms, d_hat=d_hat, recfg_frac=recfg_frac,
                     name="oblivious")
 

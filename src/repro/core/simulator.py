@@ -192,6 +192,7 @@ from .schedule import (
     planes_changed,
     vermilion_schedule,
 )
+from .tracing import span
 from .traffic import phase_train
 
 __all__ = [
@@ -1357,7 +1358,6 @@ class SweepRow:
     mode: str
     result: SimResult
     meta: dict
-    sim_s: float          # batch wall time amortized over the batch
 
 
 def run_sweep(
@@ -1401,31 +1401,31 @@ def run_sweep(
                 f"cases[{i}] ({c.label!r}): fault injection is not "
                 "implemented on the jax backend — the jax kernels have no "
                 "per-slot fault mask; use backend='numpy' for this case")
-    san = make_sanitizer(sanitize)
-    groups: dict[tuple, list[int]] = {}
-    for i, c in enumerate(cases):
-        groups.setdefault((c.wl.n, c.mode == "single_hop"), []).append(i)
-    rows: list[SweepRow | None] = [None] * len(cases)
-    for (_, single), idxs in groups.items():
-        batch = [(cases[i].sched, cases[i].wl) for i in idxs]
-        modes = [cases[i].mode for i in idxs]
-        batch_faults = [cases[i].faults for i in idxs]
-        t0 = time.perf_counter()
-        if backend == "jax":
-            results = (_singlehop_batch_jax(batch, bits_per_slot, san=san)
-                       if single
-                       else _twohop_batch_jax(batch, bits_per_slot, modes,
-                                              san=san))
-        elif single:
-            results = _simulate_batch_singlehop(
-                batch, bits_per_slot, san=san,
-                faults=batch_faults if any(batch_faults) else None)
-        else:
-            results = _simulate_batch(batch, bits_per_slot, modes, san=san)
-        dt = (time.perf_counter() - t0) / len(idxs)
-        for i, r in zip(idxs, results):
-            rows[i] = SweepRow(label=cases[i].label, mode=cases[i].mode,
-                               result=r, meta=dict(cases[i].meta), sim_s=dt)
+    with span("fabric.sweep", cases=len(cases)):
+        san = make_sanitizer(sanitize)
+        groups: dict[tuple, list[int]] = {}
+        for i, c in enumerate(cases):
+            groups.setdefault((c.wl.n, c.mode == "single_hop"), []).append(i)
+        rows: list[SweepRow | None] = [None] * len(cases)
+        for (_, single), idxs in groups.items():
+            batch = [(cases[i].sched, cases[i].wl) for i in idxs]
+            modes = [cases[i].mode for i in idxs]
+            batch_faults = [cases[i].faults for i in idxs]
+            if backend == "jax":
+                results = (_singlehop_batch_jax(batch, bits_per_slot, san=san)
+                           if single
+                           else _twohop_batch_jax(batch, bits_per_slot, modes,
+                                                  san=san))
+            elif single:
+                results = _simulate_batch_singlehop(
+                    batch, bits_per_slot, san=san,
+                    faults=batch_faults if any(batch_faults) else None)
+            else:
+                results = _simulate_batch(batch, bits_per_slot, modes,
+                                          san=san)
+            for i, r in zip(idxs, results):
+                rows[i] = SweepRow(label=cases[i].label, mode=cases[i].mode,
+                                   result=r, meta=dict(cases[i].meta))
     return rows  # type: ignore[return-value]
 
 
@@ -1735,6 +1735,10 @@ class AdaptiveCase:
     its own schedule, all concurrently, so the measured charge is one
     local construction (total wall-clock / unique views) while
     ``AdaptiveRow.construction_s`` still accounts the fabric-wide total.
+    That total is the charge the simulated dynamics see, not the host time
+    construction took: the jax path's schedule cache charges a cached
+    construction's time again on every hit.  The host time is the
+    ``fabric.construct`` spans of :mod:`repro.core.tracing`.
 
     ``method`` selects the ``vermilion_schedule`` decomposition
     (``"euler"`` fast path vs ``"hk"`` reference) — combined with
@@ -1871,12 +1875,14 @@ class AdaptiveRow:
     epoch_estimate_tv: np.ndarray   # (n_epochs,) estimate-vs-truth total-
                                     # variation distance (nan if no estimate)
     recomputes: int                 # schedule recomputations performed
-    sim_s: float
     meta: dict
     stale_slots: int = 0            # slots served by an outdated schedule
                                     # while construction was still running
-    construction_s: float = 0.0     # wall-clock spent constructing schedules
-                                    # (summed over all unique per-node views)
+    construction_s: float = 0.0     # the modelled construction charge
+                                    # (summed over all unique per-node
+                                    # views), not a layer time: on the jax
+                                    # path a sched_cache hit adds the
+                                    # cached construction's time again
     dark_slots: int = 0             # slots lost to reconfiguration darkness
                                     # (reconfig_penalty_slots per hot-swap)
     epoch_disagreement: np.ndarray = None   # type: ignore[assignment]
@@ -2338,7 +2344,7 @@ def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
     return AdaptiveRow(
         label=case.label, policy=case.policy, result=result,
         epoch_utilization=delivered_ep / ep_cap, epoch_estimate_tv=est_tv,
-        recomputes=recomputes, sim_s=0.0, meta=dict(case.meta),
+        recomputes=recomputes, meta=dict(case.meta),
         stale_slots=stale_slots, construction_s=construction_s,
         dark_slots=dark_slots,
         epoch_disagreement=dis_ep / ep_len,
@@ -2403,21 +2409,13 @@ def run_adaptive(
         for i, case in enumerate(cases):
             groups.setdefault(case.wl.n, []).append(i)
         for idxs in groups.values():
-            t0 = time.perf_counter()
             batch_rows = _run_adaptive_batch_jax(
                 [cases[i] for i in idxs], bits_per_slot, san=san)
-            dt = (time.perf_counter() - t0) / len(idxs)
             for i, row in zip(idxs, batch_rows):
-                row.sim_s = dt
                 rows_out[i] = row
         return rows_out  # type: ignore[return-value]
-    rows = []
-    for case in cases:
-        t0 = time.perf_counter()
-        row = _run_adaptive_case(case, bits_per_slot, san=san)
-        row.sim_s = time.perf_counter() - t0
-        rows.append(row)
-    return rows
+    return [_run_adaptive_case(case, bits_per_slot, san=san)
+            for case in cases]
 
 
 def _check_adaptive_jax_supported(case: "AdaptiveCase", i: int) -> None:
@@ -2919,14 +2917,29 @@ def _jax_batch_inputs(
     return caps_list, caps_flat, cap_idx, apos, asz, live, H
 
 
+def _fetch(*outputs) -> list[np.ndarray]:
+    """Host copies of kernel outputs, given as ``(array, dtype)`` pairs,
+    under one ``fabric.fetch`` span that counts the bytes copied back.
+    The first conversion waits for the kernel to finish."""
+    with span("fabric.fetch") as sp:
+        sp.add("d2h_bytes", sum(int(a.nbytes) for a, _ in outputs))
+        return [np.asarray(a, dt) for a, dt in outputs]
+
+
+def _staged(sp, *inputs) -> None:
+    """Count on the ``fabric.stage`` span ``sp`` the bytes of every array
+    handed to a kernel."""
+    sp.add("h2d_bytes", sum(int(a.nbytes) for a in inputs))
+
+
 def _jax_results(
     cases, delivered, second, bits_per_slot, modes=None
 ) -> list[SimResult]:
-    """Wrap per-slot jax outputs into SimResults (fct_slots all inf)."""
+    """Wrap the aggregate kernels' per-slot outputs, fetched to the host in
+    float64, into SimResults (fct_slots all inf)."""
     n = cases[0][1].n
-    delivered_total = np.asarray(delivered, np.float64).sum(axis=0)
-    second_total = (np.asarray(second, np.float64).sum(axis=0)
-                    if second is not None else None)
+    delivered_total = delivered.sum(axis=0)
+    second_total = second.sum(axis=0) if second is not None else None
     out = []
     for b, (sched, wl) in enumerate(cases):
         offered = float(wl.size[wl.arrival < wl.horizon].sum())
@@ -2967,48 +2980,61 @@ def _sanitize_jax_batch(
 
 
 def _twohop_fct_results(
-    cases, modes, bits_per_slot, caps_list, dp, second,
-    voq_f: np.ndarray, r3_f: np.ndarray, H: int, san,
+    cases, modes, bits_per_slot, caps_list, dp64, second64,
+    voq_f, r3_f, H: int, san,
 ) -> list[SimResult]:
     """Host side of the ``twohop_fct`` path: replay the per-slot delivered
-    (src, dst) matrices through the exact flow-credit ledger and wrap real
-    per-flow FCTs into the SimResults."""
+    (src, dst) matrices (``dp64``, fetched in float64) through the exact
+    flow-credit ledger and wrap real per-flow FCTs into the SimResults.
+    The final carry (``voq_f``, ``r3_f``) is fetched only for the
+    sanitizer."""
     B = len(cases)
     n = cases[0][1].n
     horizons = np.array([wl.horizon for _, wl in cases], dtype=np.int64)
     f_off, _, _, fct, credit, order, bucket = _concat_flows(
         cases, n, horizons, H)
-    dp64 = np.asarray(dp, np.float64)
-    for slot in range(H):
-        newf = order[bucket[slot]:bucket[slot + 1]]
-        if newf.size:
-            credit.arrive(newf)
-        credit.credit(dp64[slot].reshape(-1), slot,
-                      drain_rel=_F32_DRAIN_REL, level_rel=_F32_LEVEL_REL)
-    second64 = np.asarray(second, np.float64)
-    results = []
-    for b, (sched, wl) in enumerate(cases):
-        delivered = float(dp64[:H, b].sum())
-        sec = float(second64[:H, b].sum())
-        offered = float(wl.size[wl.arrival < wl.horizon].sum())
-        ideal = wl.horizon * n * sched.d_hat * bits_per_slot
-        results.append(SimResult(
-            fct_slots=fct[f_off[b]:f_off[b + 1]],
-            flow_size=wl.size,
-            utilization=delivered / ideal,
-            delivered_bits=delivered,
-            offered_bits=offered,
-            avg_hops=1.0 + sec / max(delivered, 1e-9),
-        ))
-    if san is not None:
-        relay_queued = r3_f.reshape(B, -1).sum(axis=1)
-        _sanitize_jax_batch(san, cases, caps_list, bits_per_slot, results,
-                            voq_f, relay_queued)
-        rem, completed = credit.remaining_active()
-        san.check_credit_closure(
-            sum(r.offered_bits for r in results),
-            sum(r.delivered_bits for r in results), rem, completed,
-            label="jax:twohop_fct:credit", float32=True)
+    with span("fabric.replay") as sp:
+        sp.add("flows_arrived", int(bucket[H] - bucket[0]))
+        arrive_ns = credited = 0
+        for slot in range(H):
+            newf = order[bucket[slot]:bucket[slot + 1]]
+            if newf.size:
+                t = time.perf_counter_ns()
+                credit.arrive(newf)
+                arrive_ns += time.perf_counter_ns() - t
+            row = dp64[slot].reshape(-1)
+            pids = np.flatnonzero(row > 1e-9)
+            credited += pids.size
+            credit.credit_pairs(pids, row[pids], slot,
+                                drain_rel=_F32_DRAIN_REL,
+                                level_rel=_F32_LEVEL_REL)
+        sp.add("pairs_credited", credited)
+        sp.add("arrive_ns", arrive_ns)
+    with span("fabric.results"):
+        results = []
+        for b, (sched, wl) in enumerate(cases):
+            delivered = float(dp64[:H, b].sum())
+            sec = float(second64[:H, b].sum())
+            offered = float(wl.size[wl.arrival < wl.horizon].sum())
+            ideal = wl.horizon * n * sched.d_hat * bits_per_slot
+            results.append(SimResult(
+                fct_slots=fct[f_off[b]:f_off[b + 1]],
+                flow_size=wl.size,
+                utilization=delivered / ideal,
+                delivered_bits=delivered,
+                offered_bits=offered,
+                avg_hops=1.0 + sec / max(delivered, 1e-9),
+            ))
+        if san is not None:
+            voq64, r3_64 = _fetch((voq_f, np.float64), (r3_f, np.float64))
+            relay_queued = r3_64.reshape(B, -1).sum(axis=1)
+            _sanitize_jax_batch(san, cases, caps_list, bits_per_slot,
+                                results, voq64, relay_queued)
+            rem, completed = credit.remaining_active()
+            san.check_credit_closure(
+                sum(r.offered_bits for r in results),
+                sum(r.delivered_bits for r in results), rem, completed,
+                label="jax:twohop_fct:credit", float32=True)
     return results
 
 
@@ -3049,35 +3075,40 @@ def _singlehop_jax_flows(
 
 
 def _replay_credit(credit: _CreditState, order: np.ndarray,
-                   bucket: np.ndarray, p_pid: np.ndarray, tx, drained,
-                   H: int) -> np.ndarray:
-    """Replay the device scan's per-slot delivered support through the
-    exact f64 flow-credit ledger: arrivals enter in the same stable order
-    as the numpy engines, then each slot's (pid, tx) support is credited
-    with drain reconciliation (``drain`` flags + ``_F32_DRAIN_REL``).
-    Returns the per-slot tx widened to f64 for the delivered-bits sums."""
-    pid64 = np.asarray(p_pid, np.int64)
-    tx64 = np.asarray(tx, np.float64)
-    dr = np.asarray(drained, bool)
-    # one vectorized pass extracts each slot's nonzero support (np.nonzero
-    # is row-major, so per-slot runs are contiguous); the loop then feeds
-    # credit_pairs pre-filtered columns and skips dark/empty slots outright
-    live = (tx64[:H] > 1e-9) | dr[:H]
-    nz_row, nz_col = np.nonzero(live)
-    bnd = np.concatenate([[0], np.cumsum(live.sum(axis=1))])
-    pid_nz = pid64[nz_row, nz_col]
-    s_nz = tx64[nz_row, nz_col]
-    dr_nz = dr[nz_row, nz_col]
-    for slot in range(H):
-        newf = order[bucket[slot]:bucket[slot + 1]]
-        if newf.size:
-            credit.arrive(newf)
-        a, b = bnd[slot], bnd[slot + 1]
-        if a == b:
-            continue
-        credit.credit_pairs(pid_nz[a:b], s_nz[a:b], slot,
-                            drain=dr_nz[a:b], drain_rel=_F32_DRAIN_REL)
-    return tx64
+                   bucket: np.ndarray, p_pid: np.ndarray, tx64: np.ndarray,
+                   dr: np.ndarray, H: int) -> None:
+    """Replay the device scan's per-slot delivered support (``tx64`` and
+    the ``dr`` drain flags, fetched to the host) through the exact f64
+    flow-credit ledger: arrivals enter in the same stable order as the
+    numpy engines, then each slot's (pid, tx) support is credited with
+    drain reconciliation (``drain`` flags + ``_F32_DRAIN_REL``)."""
+    with span("fabric.replay") as sp:
+        pid64 = np.asarray(p_pid, np.int64)
+        # one vectorized pass extracts each slot's nonzero support
+        # (np.nonzero is row-major, so per-slot runs are contiguous); the
+        # loop then feeds credit_pairs pre-filtered columns and skips
+        # dark/empty slots outright
+        live = (tx64[:H] > 1e-9) | dr[:H]
+        nz_row, nz_col = np.nonzero(live)
+        bnd = np.concatenate([[0], np.cumsum(live.sum(axis=1))])
+        pid_nz = pid64[nz_row, nz_col]
+        s_nz = tx64[nz_row, nz_col]
+        dr_nz = dr[nz_row, nz_col]
+        sp.add("flows_arrived", int(bucket[H] - bucket[0]))
+        sp.add("pairs_credited", len(nz_row))
+        arrive_ns = 0
+        for slot in range(H):
+            newf = order[bucket[slot]:bucket[slot + 1]]
+            if newf.size:
+                t = time.perf_counter_ns()
+                credit.arrive(newf)
+                arrive_ns += time.perf_counter_ns() - t
+            a, b = bnd[slot], bnd[slot + 1]
+            if a == b:
+                continue
+            credit.credit_pairs(pid_nz[a:b], s_nz[a:b], slot,
+                                drain=dr_nz[a:b], drain_rel=_F32_DRAIN_REL)
+        sp.add("arrive_ns", arrive_ns)
 
 
 def _singlehop_batch_jax(
@@ -3103,59 +3134,70 @@ def _singlehop_batch_jax(
     horizons = np.array([wl.horizon for _, wl in cases], dtype=np.int64)
     H = int(horizons.max())
     H_pad = _pad_to(H, _PAD_H)
+    with span("fabric.batch", kernel="singlehop", B=B, n=n, H_pad=H_pad):
+        with span("fabric.stage") as sp:
+            # per-case padded circuit plans -> per-case column blocks of
+            # one (H_pad, J_total) plan; capacities zero past a case's
+            # horizon
+            padded = [sched.slot_circuits_padded(bits_per_slot,
+                                                 pair_base=b * n * n,
+                                                 j_pad=_PAD_J)
+                      for b, (sched, _) in enumerate(cases)]
+            offs = np.concatenate(
+                [[0], np.cumsum([p[0].shape[1] for p in padded])]
+            ).astype(np.int64)
+            Jtot = int(offs[-1])
+            p_pid = np.zeros((H_pad, Jtot), dtype=np.int32)
+            p_cap = np.zeros((H_pad, Jtot), dtype=np.float32)
+            slots = np.arange(H)
+            for b, (ppid, pcap) in enumerate(padded):
+                ps = slots % ppid.shape[0]
+                h_b = int(horizons[b])
+                p_pid[:H, offs[b]:offs[b + 1]] = ppid[ps]
+                p_cap[:h_b, offs[b]:offs[b + 1]] = pcap[ps[:h_b]]
 
-    # per-case padded circuit plans -> per-case column blocks of one
-    # (H_pad, J_total) plan; capacities zero past a case's horizon
-    padded = [sched.slot_circuits_padded(bits_per_slot,
-                                         pair_base=b * n * n, j_pad=_PAD_J)
-              for b, (sched, _) in enumerate(cases)]
-    offs = np.concatenate(
-        [[0], np.cumsum([p[0].shape[1] for p in padded])]).astype(np.int64)
-    Jtot = int(offs[-1])
-    p_pid = np.zeros((H_pad, Jtot), dtype=np.int32)
-    p_cap = np.zeros((H_pad, Jtot), dtype=np.float32)
-    slots = np.arange(H)
-    for b, (ppid, pcap) in enumerate(padded):
-        ps = slots % ppid.shape[0]
-        h_b = int(horizons[b])
-        p_pid[:H, offs[b]:offs[b + 1]] = ppid[ps]
-        p_cap[:h_b, offs[b]:offs[b + 1]] = pcap[ps[:h_b]]
+            f_off, fct, credit, order, bucket, apid, asz = (
+                _singlehop_jax_flows([wl for _, wl in cases], n, horizons,
+                                     H, H_pad))
+            voq0 = np.zeros(B * n * n, dtype=np.float32)  # lint: allow-dense
+            _staged(sp, voq0, apid, asz, p_pid, p_cap)
+        with span("fabric.dispatch"):
+            _record_call("singlehop", (B, n, H_pad, apid.shape[1], Jtot))
+            voq_f, (tx, drained) = fns["singlehop"](voq0, apid, asz, p_pid,
+                                                    p_cap)
+        tx64, dr = _fetch((tx, np.float64), (drained, bool))
+        _replay_credit(credit, order, bucket, p_pid, tx64, dr, H)
 
-    f_off, fct, credit, order, bucket, apid, asz = _singlehop_jax_flows(
-        [wl for _, wl in cases], n, horizons, H, H_pad)
-    voq0 = np.zeros(B * n * n, dtype=np.float32)  # lint: allow-dense
-    _record_call("singlehop", (B, n, H_pad, apid.shape[1], Jtot))
-    voq_f, (tx, drained) = fns["singlehop"](voq0, apid, asz, p_pid, p_cap)
-    tx64 = _replay_credit(credit, order, bucket, p_pid, tx, drained, H)
-
-    results = []
-    for b, (sched, wl) in enumerate(cases):
-        cols = slice(int(offs[b]), int(offs[b + 1]))
-        delivered = float(tx64[:int(horizons[b]), cols].sum())
-        offered = float(wl.size[wl.arrival < wl.horizon].sum())
-        ideal = wl.horizon * n * sched.d_hat * bits_per_slot
-        results.append(SimResult(
-            fct_slots=fct[f_off[b]:f_off[b + 1]],
-            flow_size=wl.size,
-            utilization=delivered / ideal,
-            delivered_bits=delivered,
-            offered_bits=offered,
-            avg_hops=1.0,
-        ))
-    if san is not None:
-        voq64 = np.asarray(voq_f, np.float64)
-        for b, (sched, wl) in enumerate(cases):
-            san.check_workload(wl)
-            san.check_schedule(sched)
-            queued = float(voq64[b * n * n:(b + 1) * n * n].sum())
-            san.check_conservation(
-                results[b].offered_bits, results[b].delivered_bits, queued,
-                label=f"jax:case{b}:conservation", float32=True)
-        rem, completed = credit.remaining_active()
-        san.check_credit_closure(
-            sum(r.offered_bits for r in results),
-            sum(r.delivered_bits for r in results), rem, completed,
-            label="jax:singlehop:credit", float32=True)
+        with span("fabric.results"):
+            results = []
+            for b, (sched, wl) in enumerate(cases):
+                cols = slice(int(offs[b]), int(offs[b + 1]))
+                delivered = float(tx64[:int(horizons[b]), cols].sum())
+                offered = float(wl.size[wl.arrival < wl.horizon].sum())
+                ideal = wl.horizon * n * sched.d_hat * bits_per_slot
+                results.append(SimResult(
+                    fct_slots=fct[f_off[b]:f_off[b + 1]],
+                    flow_size=wl.size,
+                    utilization=delivered / ideal,
+                    delivered_bits=delivered,
+                    offered_bits=offered,
+                    avg_hops=1.0,
+                ))
+            if san is not None:
+                voq64, = _fetch((voq_f, np.float64))
+                for b, (sched, wl) in enumerate(cases):
+                    san.check_workload(wl)
+                    san.check_schedule(sched)
+                    queued = float(voq64[b * n * n:(b + 1) * n * n].sum())
+                    san.check_conservation(
+                        results[b].offered_bits, results[b].delivered_bits,
+                        queued, label=f"jax:case{b}:conservation",
+                        float32=True)
+                rem, completed = credit.remaining_active()
+                san.check_credit_closure(
+                    sum(r.offered_bits for r in results),
+                    sum(r.delivered_bits for r in results), rem, completed,
+                    label="jax:singlehop:credit", float32=True)
     return results
 
 
@@ -3188,66 +3230,81 @@ def _twohop_batch_jax(
     fns = _jax_fns()
     B = len(cases)
     n = cases[0][1].n
-    caps_list, caps_flat, cap_idx, apos, asz, live, H = _jax_batch_inputs(
-        cases, bits_per_slot)
-    H_pad = asz.shape[0]
-    direct = np.array([0.0 if m == "vlb" else 1.0 for m in modes],
-                      dtype=np.float32).reshape(B, 1, 1)
-    if kernel is None and _twohop_fct_ok(B, n, H_pad):
-        _record_call("twohop_fct", (B, n, H_pad, asz.shape[1]))
-        (dp, second), (voq_f, r3_f) = fns["twohop_fct"](
-            caps_flat, cap_idx, apos, asz, live, direct)
-        return _twohop_fct_results(
-            cases, modes, bits_per_slot, caps_list, dp, second,
-            np.asarray(voq_f, np.float64),
-            np.asarray(r3_f, np.float64), H, san)
+    H_pad = _pad_to(max(wl.horizon for _, wl in cases), _PAD_H)
+    fct_path = kernel is None and _twohop_fct_ok(B, n, H_pad)
     if kernel is None:
         kernel = "dense" if n <= _TWOHOP_DENSE_MAX_N else "sparse"
-    if kernel == "dense":
-        _record_call("twohop_dense", (B, n, H_pad, asz.shape[1]))
-        (delivered, second), (voq_f, rs_f) = fns["twohop_dense"](
-            caps_flat, cap_idx, apos, asz, live, direct)
-    elif kernel == "sparse":
-        plans = _SupportPlans(caps_list, n, list(range(B)), B)
-        keys: dict[tuple, int] = {}
-        plan_idx = np.zeros(apos.shape[0], dtype=np.int32)
-        plan_list: list[dict] = []
-        for slot in range(H):
-            key = plans.key(slot)
-            pi = keys.get(key)
-            if pi is None:
-                pi = keys[key] = len(plan_list)
-                plan_list.append(plans.plan(slot))
-            plan_idx[slot] = pi
-        J = _pad_to(max((p["J"] for p in plan_list), default=0), _PAD_J)
-        # pad the plan count to a power-of-two bucket: coprime period
-        # mixes multiply distinct residue tuples toward lcm(periods), and
-        # an unpadded P would make every mix a fresh jit signature (the
-        # LUT itself stays bounded by H — at most one plan per slot)
-        P = 1 << (max(len(plan_list), 1) - 1).bit_length()
-        p_row = np.zeros((P, J), dtype=np.int32)
-        p_v = np.zeros((P, J), dtype=np.int32)
-        p_b = np.zeros((P, J), dtype=np.int32)
-        p_valid = np.zeros((P, J), dtype=bool)
-        for i, p in enumerate(plan_list):
-            j = p["J"]
-            p_row[i, :j] = p["row"]
-            p_v[i, :j] = p["v"]
-            p_b[i, :j] = p["b"]
-            p_valid[i, :j] = True
-        _record_call("twohop_sparse", (B, n, H_pad, asz.shape[1], J, P))
-        (delivered, second), (voq_f, rs_f) = fns["twohop_sparse"](
-            caps_flat, cap_idx, apos, asz, live, plan_idx,
-            p_row, p_v, p_b, p_valid, direct)
-    else:
+    if kernel not in ("dense", "sparse"):
         raise ValueError(kernel)
-    results = _jax_results(cases, delivered, second, bits_per_slot, modes)
-    if san is not None:
-        relay_queued = np.asarray(rs_f, np.float64).reshape(
-            B, -1).sum(axis=1)
-        _sanitize_jax_batch(san, cases, caps_list, bits_per_slot, results,
-                            np.asarray(voq_f, np.float64), relay_queued)
+    name = "twohop_fct" if fct_path else f"twohop_{kernel}"
+    with span("fabric.batch", kernel=name, B=B, n=n, H_pad=H_pad):
+        with span("fabric.stage") as sp:
+            caps_list, caps_flat, cap_idx, apos, asz, live, H = (
+                _jax_batch_inputs(cases, bits_per_slot))
+            direct = np.array([0.0 if m == "vlb" else 1.0 for m in modes],
+                              dtype=np.float32).reshape(B, 1, 1)
+            lut = (_sparse_plan_lut(caps_list, n, B, H, H_pad)
+                   if name == "twohop_sparse" else [])
+            inputs = [caps_flat, cap_idx, apos, asz, live, *lut, direct]
+            _staged(sp, *inputs)
+        with span("fabric.dispatch"):
+            bucket = (B, n, H_pad, asz.shape[1])
+            if lut:
+                bucket += lut[1].shape[::-1]      # (J, P) of the plan LUT
+            _record_call(name, bucket)
+            out, (voq_f, relay_f) = fns[name](*inputs)
+        delivered, second = _fetch((out[0], np.float64),
+                                   (out[1], np.float64))
+        if fct_path:
+            return _twohop_fct_results(
+                cases, modes, bits_per_slot, caps_list, delivered, second,
+                voq_f, relay_f, H, san)
+        with span("fabric.results"):
+            results = _jax_results(cases, delivered, second, bits_per_slot,
+                                   modes)
+            if san is not None:
+                voq64, rs64 = _fetch((voq_f, np.float64),
+                                     (relay_f, np.float64))
+                _sanitize_jax_batch(san, cases, caps_list, bits_per_slot,
+                                    results, voq64,
+                                    rs64.reshape(B, -1).sum(axis=1))
     return results
+
+
+def _sparse_plan_lut(caps_list, n: int, B: int, H: int,
+                     H_pad: int) -> list[np.ndarray]:
+    """The ``twohop_sparse`` kernel's circuit-support LUT: one padded plan
+    per distinct period-residue tuple (the same :class:`_SupportPlans`
+    merge the NumPy engine uses) and each slot's index into it.  Returns
+    ``[plan_idx, p_row, p_v, p_b, p_valid]``."""
+    plans = _SupportPlans(caps_list, n, list(range(B)), B)
+    keys: dict[tuple, int] = {}
+    plan_idx = np.zeros(H_pad, dtype=np.int32)
+    plan_list: list[dict] = []
+    for slot in range(H):
+        key = plans.key(slot)
+        pi = keys.get(key)
+        if pi is None:
+            pi = keys[key] = len(plan_list)
+            plan_list.append(plans.plan(slot))
+        plan_idx[slot] = pi
+    J = _pad_to(max((p["J"] for p in plan_list), default=0), _PAD_J)
+    # pad the plan count to a power-of-two bucket: coprime period mixes
+    # multiply distinct residue tuples toward lcm(periods), and an unpadded
+    # P would make every mix a fresh jit signature (the LUT itself stays
+    # bounded by H — at most one plan per slot)
+    P = 1 << (max(len(plan_list), 1) - 1).bit_length()
+    p_row = np.zeros((P, J), dtype=np.int32)
+    p_v = np.zeros((P, J), dtype=np.int32)
+    p_b = np.zeros((P, J), dtype=np.int32)
+    p_valid = np.zeros((P, J), dtype=bool)
+    for i, p in enumerate(plan_list):
+        j = p["J"]
+        p_row[i, :j] = p["row"]
+        p_v[i, :j] = p["v"]
+        p_b[i, :j] = p["b"]
+        p_valid[i, :j] = True
+    return [plan_idx, p_row, p_v, p_b, p_valid]
 
 
 def simulate_aggregate_jax(
@@ -3633,116 +3690,128 @@ def _run_adaptive_batch_jax(
     horizons = np.array([c.wl.horizon for c in cases], dtype=np.int64)
     H = int(horizons.max())
     H_pad = _pad_to(H, _PAD_H)
-    sched_cache: dict = {}
-    compiled = [_compile_adaptive_plan(c, bits_per_slot, san=san,
-                                       sched_cache=sched_cache)
-                for c in cases]
+    with span("fabric.batch", kernel="singlehop", B=B, n=n, H_pad=H_pad):
+        sched_cache: dict = {}
+        compiled = [_compile_adaptive_plan(c, bits_per_slot, san=san,
+                                           sched_cache=sched_cache)
+                    for c in cases]
 
-    # cases whose compiled data plane is byte-identical (same workload
-    # object, horizon and per-slot circuit plan) have identical device
-    # dynamics and identical per-flow FCTs, so they are served and
-    # replayed once — e.g. the complete-gather case under every collision
-    # mode: a consistent fabric never invokes collision resolution.  The
-    # equivalence only emerges from the compiled trajectory, which is why
-    # the slot-driven numpy engine cannot exploit it.  Disabled under the
-    # sanitizer so its per-case conservation/closure ledgers stay 1:1.
-    rep_of = list(range(B))
-    if san is None:
-        seen: dict = {}
-        for b, (case, cp) in enumerate(zip(cases, compiled)):
-            hsh = hashlib.sha1(cp["plan_ids"].tobytes())
-            for spid_l, scap_l in cp["registry"]:
-                hsh.update(spid_l.tobytes())
-                hsh.update(scap_l.tobytes())
-            key = (id(case.wl), int(horizons[b]), hsh.hexdigest())
-            rep_of[b] = seen.setdefault(key, b)
-    reps = sorted(set(rep_of))
-    uidx = {b: u for u, b in enumerate(reps)}
+        # cases whose compiled data plane is byte-identical (same workload
+        # object, horizon and per-slot circuit plan) have identical device
+        # dynamics and identical per-flow FCTs, so they are served and
+        # replayed once — e.g. the complete-gather case under every collision
+        # mode: a consistent fabric never invokes collision resolution.  The
+        # equivalence only emerges from the compiled trajectory, which is why
+        # the slot-driven numpy engine cannot exploit it.  Disabled under the
+        # sanitizer so its per-case conservation/closure ledgers stay 1:1.
+        rep_of = list(range(B))
+        if san is None:
+            seen: dict = {}
+            for b, (case, cp) in enumerate(zip(cases, compiled)):
+                hsh = hashlib.sha1(cp["plan_ids"].tobytes())
+                for spid_l, scap_l in cp["registry"]:
+                    hsh.update(spid_l.tobytes())
+                    hsh.update(scap_l.tobytes())
+                key = (id(case.wl), int(horizons[b]), hsh.hexdigest())
+                rep_of[b] = seen.setdefault(key, b)
+        reps = sorted(set(rep_of))
+        uidx = {b: u for u, b in enumerate(reps)}
 
-    col_offs = [0]
-    for b in reps:
-        cp = compiled[b]
-        max_j = max((len(p[0]) for p in cp["registry"]), default=0)
-        col_offs.append(col_offs[-1] + _pad_to(max(max_j, 1), _PAD_J))
-    Jtot = col_offs[-1]
-    p_pid = np.zeros((H_pad, Jtot), dtype=np.int32)
-    p_cap = np.zeros((H_pad, Jtot), dtype=np.float32)
-    for u, b in enumerate(reps):
-        cp = compiled[b]
-        base = u * n * n
-        cols = slice(col_offs[u], col_offs[u + 1])
-        jc = col_offs[u + 1] - col_offs[u]
-        reg = cp["registry"]
-        ent_pid = np.full((len(reg), jc), base, dtype=np.int32)
-        ent_cap = np.zeros((len(reg), jc), dtype=np.float32)
-        for i, (spid_l, scap_l) in enumerate(reg):
-            ent_pid[i, :len(spid_l)] = base + spid_l
-            ent_cap[i, :len(spid_l)] = scap_l
-        h_b = int(horizons[b])
-        p_pid[:h_b, cols] = ent_pid[cp["plan_ids"]]
-        p_cap[:h_b, cols] = ent_cap[cp["plan_ids"]]
-        p_pid[h_b:, cols] = base
+        with span("fabric.stage") as sp:
+            col_offs = [0]
+            for b in reps:
+                cp = compiled[b]
+                max_j = max((len(p[0]) for p in cp["registry"]), default=0)
+                col_offs.append(col_offs[-1]
+                                + _pad_to(max(max_j, 1), _PAD_J))
+            Jtot = col_offs[-1]
+            p_pid = np.zeros((H_pad, Jtot), dtype=np.int32)
+            p_cap = np.zeros((H_pad, Jtot), dtype=np.float32)
+            for u, b in enumerate(reps):
+                cp = compiled[b]
+                base = u * n * n
+                cols = slice(col_offs[u], col_offs[u + 1])
+                jc = col_offs[u + 1] - col_offs[u]
+                reg = cp["registry"]
+                ent_pid = np.full((len(reg), jc), base, dtype=np.int32)
+                ent_cap = np.zeros((len(reg), jc), dtype=np.float32)
+                for i, (spid_l, scap_l) in enumerate(reg):
+                    ent_pid[i, :len(spid_l)] = base + spid_l
+                    ent_cap[i, :len(spid_l)] = scap_l
+                h_b = int(horizons[b])
+                p_pid[:h_b, cols] = ent_pid[cp["plan_ids"]]
+                p_cap[:h_b, cols] = ent_cap[cp["plan_ids"]]
+                p_pid[h_b:, cols] = base
 
-    f_off, fct, credit, order, bucket, apid, asz = _singlehop_jax_flows(
-        [cases[b].wl for b in reps], n, horizons[reps], H, H_pad)
-    voq0 = np.zeros(len(reps) * n * n, dtype=np.float32)  # lint: allow-dense
-    _record_call("singlehop", (len(reps), n, H_pad, apid.shape[1], Jtot))
-    voq_f, (tx, drained) = fns["singlehop"](voq0, apid, asz, p_pid, p_cap)
-    tx64 = _replay_credit(credit, order, bucket, p_pid, tx, drained, H)
-    voq64 = np.asarray(voq_f, np.float64)
+            f_off, fct, credit, order, bucket, apid, asz = (
+                _singlehop_jax_flows([cases[b].wl for b in reps], n,
+                                     horizons[reps], H, H_pad))
+            voq0 = np.zeros(len(reps) * n * n,  # lint: allow-dense
+                            dtype=np.float32)
+            _staged(sp, voq0, apid, asz, p_pid, p_cap)
+        with span("fabric.dispatch"):
+            _record_call("singlehop",
+                         (len(reps), n, H_pad, apid.shape[1], Jtot))
+            voq_f, (tx, drained) = fns["singlehop"](voq0, apid, asz, p_pid,
+                                                    p_cap)
+        tx64, dr = _fetch((tx, np.float64), (drained, bool))
+        _replay_credit(credit, order, bucket, p_pid, tx64, dr, H)
 
-    rows = []
-    for b, (case, cp) in enumerate(zip(cases, compiled)):
-        wl, E = case.wl, case.epoch_slots
-        h_b = int(horizons[b])
-        n_epochs = cp["n_epochs"]
-        u = uidx[rep_of[b]]
-        cols = slice(col_offs[u], col_offs[u + 1])
-        # strictly sequential per-epoch accumulation (np.add.at, not
-        # reduceat: reduceat's pairwise float reduction drifts ~1 ulp from
-        # the numpy loop's slot-by-slot `+=`)
-        ep_idx = np.arange(h_b) // E
-        per_slot = tx64[:h_b, cols].sum(axis=1)
-        delivered_ep = np.zeros(n_epochs)
-        np.add.at(delivered_ep, ep_idx, per_slot)
-        dis_ep = np.zeros(n_epochs)
-        np.add.at(dis_ep, ep_idx, cp["dis_slot"])
-        coll_ep = np.zeros(n_epochs)
-        np.add.at(coll_ep, ep_idx, cp["coll_slot"])
-        ep_len = np.minimum(E, h_b - E * np.arange(n_epochs))
-        ep_cap = ep_len * n * case.d_hat * bits_per_slot
-        ideal = h_b * n * case.d_hat * bits_per_slot
-        delivered = float(delivered_ep.sum())
-        offered = float(wl.size[wl.arrival < h_b].sum())
-        if san is not None:
-            queued = float(voq64[u * n * n:(u + 1) * n * n].sum())
-            san.check_conservation(
-                offered, delivered, queued,
-                label=f"jax:adaptive{b}:conservation", float32=True)
-        result = SimResult(
-            fct_slots=fct[f_off[u]:f_off[u + 1]],
-            flow_size=wl.size,
-            utilization=delivered / ideal,
-            delivered_bits=delivered,
-            offered_bits=offered,
-        )
-        rows.append(AdaptiveRow(
-            label=case.label, policy=case.policy, result=result,
-            epoch_utilization=delivered_ep / ep_cap,
-            epoch_estimate_tv=cp["est_tv"],
-            recomputes=cp["recomputes"], sim_s=0.0, meta=dict(case.meta),
-            stale_slots=cp["stale_slots"],
-            construction_s=cp["construction_s"],
-            dark_slots=cp["dark_slots"],
-            epoch_disagreement=dis_ep / ep_len,
-            epoch_collision_loss=coll_ep / ep_cap,
-            collision_lost_bits=float(coll_ep.sum()),
-            schedule_groups_max=cp["groups_max"],
-            dark_plane_slots=cp["dark_plane_slots"]))
-    if san is not None:
-        rem, completed = credit.remaining_active()
-        san.check_credit_closure(
-            sum(r.result.offered_bits for r in rows),
-            sum(r.result.delivered_bits for r in rows), rem, completed,
-            label="jax:adaptive:credit", float32=True)
+        with span("fabric.results"):
+            if san is not None:
+                voq64, = _fetch((voq_f, np.float64))
+            rows = []
+            for b, (case, cp) in enumerate(zip(cases, compiled)):
+                wl, E = case.wl, case.epoch_slots
+                h_b = int(horizons[b])
+                n_epochs = cp["n_epochs"]
+                u = uidx[rep_of[b]]
+                cols = slice(col_offs[u], col_offs[u + 1])
+                # strictly sequential per-epoch accumulation (np.add.at,
+                # not reduceat: reduceat's pairwise float reduction drifts
+                # ~1 ulp from the numpy loop's slot-by-slot `+=`)
+                ep_idx = np.arange(h_b) // E
+                per_slot = tx64[:h_b, cols].sum(axis=1)
+                delivered_ep = np.zeros(n_epochs)
+                np.add.at(delivered_ep, ep_idx, per_slot)
+                dis_ep = np.zeros(n_epochs)
+                np.add.at(dis_ep, ep_idx, cp["dis_slot"])
+                coll_ep = np.zeros(n_epochs)
+                np.add.at(coll_ep, ep_idx, cp["coll_slot"])
+                ep_len = np.minimum(E, h_b - E * np.arange(n_epochs))
+                ep_cap = ep_len * n * case.d_hat * bits_per_slot
+                ideal = h_b * n * case.d_hat * bits_per_slot
+                delivered = float(delivered_ep.sum())
+                offered = float(wl.size[wl.arrival < h_b].sum())
+                if san is not None:
+                    queued = float(voq64[u * n * n:(u + 1) * n * n].sum())
+                    san.check_conservation(
+                        offered, delivered, queued,
+                        label=f"jax:adaptive{b}:conservation", float32=True)
+                result = SimResult(
+                    fct_slots=fct[f_off[u]:f_off[u + 1]],
+                    flow_size=wl.size,
+                    utilization=delivered / ideal,
+                    delivered_bits=delivered,
+                    offered_bits=offered,
+                )
+                rows.append(AdaptiveRow(
+                    label=case.label, policy=case.policy, result=result,
+                    epoch_utilization=delivered_ep / ep_cap,
+                    epoch_estimate_tv=cp["est_tv"],
+                    recomputes=cp["recomputes"], meta=dict(case.meta),
+                    stale_slots=cp["stale_slots"],
+                    construction_s=cp["construction_s"],
+                    dark_slots=cp["dark_slots"],
+                    epoch_disagreement=dis_ep / ep_len,
+                    epoch_collision_loss=coll_ep / ep_cap,
+                    collision_lost_bits=float(coll_ep.sum()),
+                    schedule_groups_max=cp["groups_max"],
+                    dark_plane_slots=cp["dark_plane_slots"]))
+            if san is not None:
+                rem, completed = credit.remaining_active()
+                san.check_credit_closure(
+                    sum(r.result.offered_bits for r in rows),
+                    sum(r.result.delivered_bits for r in rows), rem,
+                    completed, label="jax:adaptive:credit", float32=True)
     return rows
