@@ -44,9 +44,10 @@ through one slot loop with a leading batch axis:
    water-fill subtracts the *same* level from every surviving flow of a
    pair: per-pair offsets advance in O(1) (``true_rem = stored - off``),
    the level is solved on a bounded sorted-prefix pad with an exact
-   fallback, and completions pop the sorted prefix via tombstone counters
-   with periodic compaction.  No per-pair Python loop, no dict
-   bookkeeping, and per-slot cost independent of queue depth.
+   fallback, and completions pop the sorted prefix.  Each pair's flows
+   live in a fixed slab of the ledger, so an arrival moves only entries
+   of its own pair.  No per-pair Python loop, no dict bookkeeping, and
+   per-slot cost independent of queue depth.
 
 5. **Sweep API.**  :func:`run_sweep` takes a list of
    ``(schedule, workload, mode)`` cases (see :class:`SweepCase`), batches
@@ -563,7 +564,6 @@ def simulate_reference(
 # ---------------------------------------------------------------------------
 
 _PAD_W = 8           # water-level search depth before exact fallback
-_KEY_DT = np.dtype([("p", np.int64), ("r", np.float64)])
 
 
 def _ranged_arange(counts: np.ndarray) -> np.ndarray:
@@ -577,15 +577,24 @@ def _ranged_arange(counts: np.ndarray) -> np.ndarray:
 class _CreditState:
     """Processor-sharing flow-completion bookkeeping, O(pairs) per slot.
 
-    Active flows are kept in arrays sorted by (pair id, stored size).  A
-    water-fill step subtracts the same level from every surviving flow of a
-    pair, so the engine stores per-pair *offsets* instead of rewriting
+    A water-fill step subtracts the same level from every surviving flow of
+    a pair, so the engine stores per-pair *offsets* instead of rewriting
     per-flow remainders: ``true_remaining = stored - off[pair]``.  A slot
     then costs O(1) per delivered pair (advance the offset, complete the
     sorted prefix that sank below the level) instead of O(active flows).
-    Completions are tombstoned via per-pair skip counters and physically
-    removed in periodic compactions, which also rebase offsets before they
-    grow past float precision.
+
+    Each pair owns a fixed slab of the ledger, twice as long as its flows,
+    laid out in pair order.  ``key`` holds (pair id, stored) as one
+    complex number per entry, ``stored`` its imaginary view, and ``act``
+    the flow ids.  A pair's live run, sorted by stored, is ``[lo, hi)`` of
+    its slab and starts in its middle.  Below the run every entry is -inf
+    (completed flows leave it at the front and are set so), above it
+    +inf, so ``key`` stays sorted as a whole and one ``searchsorted``
+    places arrivals inside their own runs.  An arrival moves only its own
+    run's entries on the cheaper side of it, down or up; at most all of
+    the pair's flows enter on one side, so the run never reaches either
+    end of the slab.  Offsets are rebased into the stored values before
+    they grow past float precision (:meth:`_rebase`).
 
     Matches :class:`_FlowTracker.credit` semantics (per pair, bits are
     water-filled across active flows sorted by remaining size; flows
@@ -601,63 +610,91 @@ class _CreditState:
         self.fct = fct
         self.off = np.zeros(n_pairs)      # per-pair water level served
         self.psum = np.zeros(n_pairs)     # approx total remaining per pair
-        self.ctr = np.zeros(n_pairs, dtype=np.int64)   # tombstoned prefix
-        self.keys = np.empty(0, dtype=_KEY_DT)         # (pair, stored)
-        self.act = np.empty(0, dtype=np.int64)         # flow ids
+        # a pair's slab holds twice its flows and its live run [lo, hi)
+        # starts in the middle, so either side has room for every arrival
+        flows = np.bincount(pid, minlength=n_pairs)
+        self.cap = 2 * flows
+        self.lo = np.cumsum(self.cap) - flows
+        self.hi = self.lo.copy()
+        used = np.flatnonzero(flows)
+        self.key = np.empty(2 * len(pid), dtype=np.complex128)
+        self.key.real = np.repeat(used, self.cap[used])
+        self.key.imag = np.repeat(np.tile([-np.inf, np.inf], len(used)),
+                                  np.repeat(flows[used], 2))
+        self.stored = self.key.imag
+        self.act = np.zeros(len(self.key), dtype=np.int64)  # flow ids
+        # entries counted since the last rebase point, and completed ones
+        self.entries = 0
         self.dead = 0
+        self.moved = 0                    # ledger entries written by arrive
 
     def arrive(self, newf: np.ndarray) -> None:
-        # the insert below rewrites the whole keys/act arrays, so shedding
-        # tombstones first keeps every later O(active) pass proportional
-        # to genuinely alive flows (the batched replay ledger otherwise
-        # drags ~1/3 dead entries through each rebuild)
-        if self.dead * 4 > len(self.act) and self.dead > 1024:
-            self._compact()
+        if self.dead * 4 > self.entries and self.dead > 1024:
+            self._rebase()
+        K = len(newf)
         npid = self.pid[newf]
-        stored = self.size[newf] + self.off[npid]
-        o = np.lexsort((stored, npid))
-        newf, npid, stored = newf[o], npid[o], stored[o]
+        q = npid + 1j * (self.size[newf] + self.off[npid])
+        o = q.argsort(kind="stable")            # by (pair, stored), stable
+        newf, npid, q = newf[o], npid[o], q[o]
         np.add.at(self.psum, npid, self.size[newf])
-        q = np.empty(len(newf), dtype=_KEY_DT)
-        q["p"] = npid
-        q["r"] = stored
-        if self.keys.size:
-            # hand-rolled sorted insert (np.insert x2 costs several passes)
-            K, A = len(q), len(self.keys)
-            tgt = np.searchsorted(self.keys, q, side="left") + np.arange(K)
-            keys = np.empty(A + K, dtype=_KEY_DT)
-            act = np.empty(A + K, dtype=np.int64)
-            keep = np.ones(A + K, dtype=bool)
-            keep[tgt] = False
-            keys[tgt] = q
-            act[tgt] = newf
-            keys[keep] = self.keys
-            act[keep] = self.act
-            self.keys, self.act = keys, act
-        else:
-            self.keys = q
-            self.act = newf.copy()
+        # each one's place: before live entries of equal key, past the -inf
+        # below its pair's run
+        ins = self.key.searchsorted(q)
+        brk = (npid[1:] != npid[:-1]).nonzero()[0] + 1
+        first = np.concatenate(([0], brk))      # each pair's new flows
+        end = np.concatenate((brk, [K]))
+        cnt = end - first
+        up = npid[first]
+        lo, hi = self.lo[up], self.hi[up]
+        i0, i1 = ins[first], ins[end - 1]
+        # the run's entries [s0, s0 + n_old) and the new flows merge into
+        # the span from s0 - dcnt: from the first insertion point up, or,
+        # where fewer entries move, from the run's start down to its last
+        # insertion point
+        down = i1 - lo < hi - i0
+        dcnt = cnt * down
+        s0 = np.where(down, lo, i0)
+        n_old = np.where(down, i1, hi) - s0
+        tgt = ins + np.arange(K) - (first + dcnt).repeat(cnt)
+        moved = int(n_old.sum())
+        if moved:
+            span = n_old + cnt
+            sh = s0 - dcnt - span.cumsum() + span     # span index -> ledger
+            new = np.zeros(moved + K, dtype=bool)
+            new[tgt - sh.repeat(cnt)] = True
+            dst = (np.arange(moved + K) + sh.repeat(span))[~new]
+            src = (np.arange(moved)
+                   + (s0 - n_old.cumsum() + n_old).repeat(n_old))
+            self.key[dst] = self.key[src]
+            self.act[dst] = self.act[src]
+        self.key[tgt] = q
+        self.act[tgt] = newf
+        self.lo[up] = lo - dcnt
+        self.hi[up] = hi + cnt - dcnt
+        self.entries += K
+        self.moved += K + moved
 
     def remaining_active(self) -> tuple[float, int]:
         """(total bits still stored for uncompleted flows, completed count)
         — the sanitizer's credit-closure probe; read-only."""
         completed = int(np.isfinite(self.fct).sum())
-        if not self.act.size:
+        m = self.hi - self.lo
+        if not m.any():
             return 0.0, completed
-        alive = np.isinf(self.fct[self.act])
-        rem = (self.keys["r"][alive]
-               - self.off[self.keys["p"][alive]])
+        pos = np.repeat(self.lo, m) + _ranged_arange(m)
+        rem = self.stored[pos] - np.repeat(self.off, m)
         return float(np.maximum(rem, 0.0).sum()), completed
 
-    def _compact(self) -> None:
-        alive = np.isinf(self.fct[self.act])
-        self.act = self.act[alive]
-        self.keys = self.keys[alive]
-        self.ctr[:] = 0
+    def _rebase(self) -> None:
+        """Forget the completed entries' count and, once an offset passes
+        1e9, fold every offset into its pair's stored values before it
+        swamps the mantissa.  The trigger points depend only on the
+        arrival and completion counts, so the float ops are the same for
+        any ledger layout."""
+        self.entries -= self.dead
         self.dead = 0
-        # rebase offsets into stored values before they swamp the mantissa
-        if self.off.max() > 1e9 and self.act.size:
-            self.keys["r"] -= self.off[self.keys["p"]]
+        if self.off.max() > 1e9 and self.entries:
+            self.stored -= np.repeat(self.off, self.cap)
             self.off[:] = 0.0
 
     def credit(self, delivered_flat: np.ndarray, slot: int,
@@ -679,7 +716,7 @@ class _CreditState:
         remaining total is forced to complete fully, so f32 rounding in the
         delivered amounts cannot leave 1-ulp residues that stall FCTs.
         """
-        if not self.act.size or not pids.size:
+        if not self.entries or not pids.size:
             return
         keep = s > 1e-9
         if drain is not None:
@@ -690,9 +727,7 @@ class _CreditState:
                 drain = drain[keep]
         if not pids.size:
             return
-        kp = self.keys["p"]
-        lo = np.searchsorted(kp, pids, side="left") + self.ctr[pids]
-        hi = np.searchsorted(kp, pids, side="right")
+        lo, hi = self.lo[pids], self.hi[pids]
         m = hi - lo
         g = m > 0
         if not g.all():
@@ -703,7 +738,7 @@ class _CreditState:
                 drain = drain[g]
         S = len(pids)
         off_g = self.off[pids]
-        stored = self.keys["r"]
+        stored = self.stored
 
         # fast path: when the pair's smallest remaining (the head of its
         # sorted run) sits above the no-completion water level s/m plus
@@ -804,18 +839,14 @@ class _CreditState:
         self.off[pids] = off_g + level
         self.psum[pids] = np.where(k == m, 0.0, self.psum[pids] - s_eff)
         if k.any():
-            kc = np.minimum(k, W)
-            fmask = (col[None, :] < kc[:, None]) & valid
-            done = self.act[safe[fmask]]
-            big = np.flatnonzero(k > W)
-            if big.size:
-                ext = np.repeat(lo[big] + W, k[big] - W)                     + _ranged_arange(k[big] - W)
-                done = np.concatenate([done, self.act[ext]])
+            pos = np.arange(k.sum()) + (lo - k.cumsum() + k).repeat(k)
+            done = self.act[pos]
             self.fct[done] = slot + 1 - self.arrival[done]
-            self.ctr[pids] += k
+            stored[pos] = -np.inf
+            self.lo[pids] += k
             self.dead += int(k.sum())
-            if self.dead * 2 > len(self.act) and self.dead > 4096:
-                self._compact()
+            if self.dead * 2 > self.entries and self.dead > 4096:
+                self._rebase()
 
 
 class _SupportPlans:
@@ -3010,6 +3041,7 @@ def _twohop_fct_results(
                                 level_rel=_F32_LEVEL_REL)
         sp.add("pairs_credited", credited)
         sp.add("arrive_ns", arrive_ns)
+        sp.add("arrive_moved", credit.moved)
     with span("fabric.results"):
         results = []
         for b, (sched, wl) in enumerate(cases):
@@ -3109,6 +3141,7 @@ def _replay_credit(credit: _CreditState, order: np.ndarray,
             credit.credit_pairs(pid_nz[a:b], s_nz[a:b], slot,
                                 drain=dr_nz[a:b], drain_rel=_F32_DRAIN_REL)
         sp.add("arrive_ns", arrive_ns)
+        sp.add("arrive_moved", credit.moved)
 
 
 def _singlehop_batch_jax(

@@ -19,6 +19,7 @@ from repro.core.schedule import oblivious_schedule, vermilion_schedule
 from repro.core.simulator import (
     AdaptiveCase,
     SweepCase,
+    Workload,
     compile_cache_stats,
     phase_shifting_workload,
     run_adaptive,
@@ -82,6 +83,30 @@ def test_sweep_fct_parity_mixed_horizons():
     for a, b in zip(rows_np, rows_jx):
         assert np.array_equal(a.result.fct_slots, b.result.fct_slots,
                               equal_nan=True), a.label
+
+
+@pytest.mark.parametrize("mode", ["single_hop", "rotorlb"])
+def test_sweep_fct_parity_hot_pair(mode):
+    """A hot pair: 240 flows on (0, 1) within 60 slots, beside websearch
+    traffic, so one pair's ledger run holds 100-230 live flows; the
+    drain-reconciled (single-hop) and level-widened (two-hop) replays
+    still give numpy's FCTs exactly."""
+    bg = websearch_workload(8, 0.3, 300, BPS, d_hat=2, seed=11)
+    rng = np.random.default_rng(11)
+    src = np.r_[bg.src, np.zeros(240, int)]
+    dst = np.r_[bg.dst, np.ones(240, int)]
+    size = np.r_[bg.size, rng.uniform(2e4, 4e5, 240)]
+    arrival = np.r_[bg.arrival, rng.integers(0, 60, 240)]
+    o = np.argsort(arrival, kind="stable")
+    wl = Workload(src=src[o], dst=dst[o], size=size[o], arrival=arrival[o],
+                  n=8, horizon=300)
+    s = oblivious_schedule(8, d_hat=2, recfg_frac=RECFG)
+    cases = [SweepCase(s, wl, mode, mode)]
+    r_np = run_sweep(cases, BPS)[0].result
+    r_jx = run_sweep(cases, BPS, backend="jax")[0].result
+    hot = (wl.src == 0) & (wl.dst == 1)
+    assert np.isfinite(r_np.fct_slots[hot]).sum() > 60
+    assert np.array_equal(r_np.fct_slots, r_jx.fct_slots, equal_nan=True)
 
 
 def test_sweep_percentiles_available_on_jax():

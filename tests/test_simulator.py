@@ -141,6 +141,118 @@ def test_golden_trace_overloaded(mode):
     assert np.isclose(a.delivered_bits, b.delivered_bits, rtol=1e-6)
 
 
+# ---------------------------------------------------------------------------
+# Flow-credit ledger against the plain processor-sharing tracker
+# ---------------------------------------------------------------------------
+
+def _hot_pair(rng):
+    """240 flows on pair (0, 1), served below their offered rate for a
+    while so the pair's run grows past 200 live flows, beside light
+    traffic on two other pairs."""
+    H = 200
+    src = np.r_[np.zeros(240, int), rng.integers(1, 3, 60)]
+    dst = np.r_[np.ones(240, int), np.zeros(60, int)]
+    size = rng.lognormal(np.log(1e5), 1.5, 300)
+    arrival = np.r_[np.sort(rng.integers(0, 80, 240)),
+                    np.sort(rng.integers(0, 150, 60))]
+    d = np.zeros((H, 3, 3))
+    d[:, 0, 1] = rng.uniform(0.5, 1.0, H) * size[:240].sum() / 80
+    d[:80, 0, 1] *= 0.05
+    d[:, 1, 0] = d[:, 2, 0] = rng.uniform(1e4, 3e5, H)
+    return 3, src, dst, size, arrival, d
+
+
+def _bursts(rng):
+    """Three bursts of 60 same-slot arrivals into pair (1, 2)."""
+    H = 120
+    arrival = np.repeat([0, 10, 20], 60)
+    src, dst = np.ones(180, int), np.full(180, 2)
+    size = rng.lognormal(np.log(5e4), 1.0, 180)
+    d = np.zeros((H, 3, 3))
+    d[:, 1, 2] = rng.uniform(0.0, 2.0, H) * size.sum() / 60
+    return 3, src, dst, size, arrival, d
+
+
+def _ties(rng):
+    """Equal sizes: 8 flows a slot of three sizes on two pairs, so runs
+    hold equal stored sizes from one slot and from several."""
+    H = 90
+    arrival = np.repeat(np.arange(30), 8)
+    src = np.tile([0, 0, 0, 0, 2, 2, 2, 2], 30)
+    dst = np.tile([2, 2, 2, 2, 1, 1, 1, 1], 30)
+    size = rng.choice([2e4, 5e4, 1e5], len(src))
+    d = np.zeros((H, 3, 3))
+    d[:, 0, 2], d[:, 2, 1] = rng.choice([0.0, 5e4, 1.5e5, 4e5], (2, H))
+    return 3, src, dst, size, arrival, d
+
+
+def _long_drain(rng):
+    """Runs of 30 flows on three pairs: one slot sinks the 20 small ones
+    (more completions than the water-level pad holds), the next drains
+    the 10 large ones in full."""
+    H = 20
+    src, dst, size, arrival = [], [], [], []
+    d = np.zeros((H, 3, 3))
+    for t, (u, v) in zip((0, 4, 8), ((1, 0), (2, 0), (0, 1))):
+        small = rng.uniform(1e3, 2e3, 20)
+        large = rng.uniform(1e6, 2e6, 10)
+        size += [*small, *large]
+        src += [u] * 30
+        dst += [v] * 30
+        arrival += [t] * 30
+        d[t + 1, u, v] = 20 * 2e3 + 10 * 3e3
+        d[t + 2, u, v] = 2.5e7
+    order = np.argsort(arrival, kind="stable")
+    return (3, np.array(src)[order], np.array(dst)[order],
+            np.array(size)[order], np.array(arrival)[order], d)
+
+
+def _rebase(rng):
+    """An elephant on pair (0, 1) lifts its offset past 1e9 while 40
+    small flows a slot complete on two other pairs, so the ledger
+    rebases its offsets; medium flows keep completing on the pair."""
+    H = 80
+    src, dst, size, arrival = [0], [1], [1e13], [0]
+    for t in range(H):
+        k = 40 + (t % 5 == 0)
+        src += [1] * 20 + [2] * 20 + [0] * (k - 40)
+        dst += [2] * 20 + [0] * 20 + [1] * (k - 40)
+        size += [*rng.uniform(1e3, 1e4, 40), *rng.uniform(1e8, 1e9, k - 40)]
+        arrival += [t] * k
+    d = np.zeros((H, 3, 3))
+    d[:, 0, 1] = rng.uniform(5e8, 7e8, H)
+    d[:, 1, 2] = d[:, 2, 0] = 3e5
+    return 3, *map(np.array, (src, dst, size, arrival)), d
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("build", [_hot_pair, _bursts, _ties, _long_drain,
+                                   _rebase])
+def test_credit_ledger_matches_flow_tracker(build, seed):
+    """The flow-credit ledger gives the plain processor-sharing tracker's
+    FCTs, one case fed exact (n, n) delivered matrices each slot."""
+    from repro.core.simulator import _CreditState, _FlowTracker
+    n, src, dst, size, arrival, deliver = build(np.random.default_rng(seed))
+    wl = Workload(src=src, dst=dst, size=size, arrival=arrival, n=n,
+                  horizon=len(deliver))
+    tracker = _FlowTracker(wl)
+    fct = np.full(wl.num_flows, np.inf)
+    ledger = _CreditState(n * n, src * n + dst, size, arrival, fct)
+    rebased = False
+    for slot, d in enumerate(deliver):
+        before = ledger.off.max()
+        f = np.flatnonzero(arrival == slot)
+        if f.size:
+            tracker.arrive(f)
+            ledger.arrive(f)
+        tracker.credit(d, slot)
+        ledger.credit(d.reshape(-1), slot)
+        rebased |= ledger.off.max() < before
+    assert np.isfinite(fct).sum() > wl.num_flows // 2
+    assert np.array_equal(tracker.fct, fct)
+    assert rebased == (build is _rebase)
+
+
 def test_run_sweep_matches_per_case_simulate():
     """One batched sweep across modes reproduces per-case results."""
     wl = websearch_workload(8, 0.4, 300, BPS, d_hat=2, seed=5)

@@ -148,14 +148,23 @@ def test_flows_arrived_counts_flows_inside_the_horizon(mode):
 
 
 def test_pairs_credited_counts_the_live_mask(monkeypatch):
-    seen = {}
+    seen = {"arrived": 0, "rewrite": 0}
     real = sim._replay_credit
+    real_arrive = sim._CreditState.arrive
 
     def spy(credit, order, bucket, p_pid, tx64, dr, H):
         seen["live"] = int(((tx64[:H] > 1e-9) | dr[:H]).sum())
         return real(credit, order, bucket, p_pid, tx64, dr, H)
 
+    def arrive(credit, newf):
+        # a ledger that rewrote every active flow and the new ones
+        active = seen["arrived"] - int(np.isfinite(credit.fct).sum())
+        seen["rewrite"] += active + len(newf)
+        seen["arrived"] += len(newf)
+        return real_arrive(credit, newf)
+
     monkeypatch.setattr(sim, "_replay_credit", spy)
+    monkeypatch.setattr(sim._CreditState, "arrive", arrive)
     cases, _ = _cases("single_hop")
     run_sweep(cases, BPS, backend="jax")
     _, recs = _tree("fabric.sweep")
@@ -163,6 +172,31 @@ def test_pairs_credited_counts_the_live_mask(monkeypatch):
     assert seen["live"] > 0
     assert replay.attrs["pairs_credited"] == seen["live"]
     assert replay.attrs["arrive_ns"] > 0
+    assert 0 < replay.attrs["arrive_moved"] < seen["rewrite"]
+
+
+def test_arrive_moved_counts_the_entries_written():
+    """Pair 0 gets sizes 3, 5, 8, 9 in slot 0 (4 written), and 12 bits
+    that complete the 3 and leave 2, 5, 6; slot 1 brings 3.5 to pair 0,
+    which lands after the 2: the 2 moves down into the completed slot
+    and the new flow is written (2), and 7 to pair 1 (1); slot 2 brings
+    20 to the end of pair 0's run (1).  8 in all, where rewriting every
+    active flow would write 4 + 5 + 6."""
+    size = np.array([3.0, 5.0, 8.0, 9.0, 3.5, 7.0, 20.0])
+    pid = np.array([0, 0, 0, 0, 0, 1, 0])
+    arrival = np.array([0, 0, 0, 0, 1, 1, 2])
+    fct = np.full(len(size), np.inf)
+    credit = sim._CreditState(2, pid, size, arrival, fct)
+    order = np.arange(len(size))
+    bucket = np.searchsorted(arrival, np.arange(4))
+    tx = np.array([[12.0], [0.0], [0.0]])
+    sim._replay_credit(credit, order, bucket, np.zeros((3, 1), np.int32),
+                       tx, np.zeros((3, 1), bool), 3)
+    replay = tracing.records()[-1]
+    assert replay.name == "fabric.replay"
+    assert replay.attrs["arrive_moved"] == 8
+    assert replay.attrs["flows_arrived"] == 7
+    assert fct[0] == 1 and np.isinf(fct[1:]).all()
 
 
 @pytest.mark.parametrize("mode,kernel,fetched", [
