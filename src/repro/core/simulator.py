@@ -2633,7 +2633,9 @@ def jax_kernels() -> dict:
 # O(n^3) offload einsum lowers to a batched matmul and beats the sparse
 # step's O(n^2 d_hat) scalarized gather/scatter constants until n is large
 # (benchmarks/fct_bench.py ``twohop_table`` on the 2-core CI CPU: dense
-# ~1.6x ahead at n = 128, ~par at 256, behind from n ~ 384 on).
+# ~1.6x ahead at n = 128, ~par at 256, behind from n ~ 384 on).  That
+# table is a CPU's; the TPU benchmark has a cell on each side of this
+# value (PERF.md section 4), and a move of the crossover is judged on both.
 _TWOHOP_DENSE_MAX_N = 256
 
 _JEPS = 1e-12
@@ -2893,7 +2895,7 @@ def _jax_fns() -> dict:
 
 
 def _jax_batch_inputs(
-    cases: list[tuple[Schedule, Workload]], bits_per_slot: float
+    cases: list[tuple[Schedule, Workload]], bits_per_slot: float, sp
 ):
     """Shared numpy-side prep for the jax engines: the periodic capacity
     LUT, per-slot liveness, and padded per-slot arrival scatter lists.
@@ -2902,6 +2904,9 @@ def _jax_batch_inputs(
     capacity, zero liveness, and no arrivals — exact no-ops), arrivals per
     slot to a ``_PAD_K`` bucket (padding scatters 0 bits at pair (0,0,0)),
     so the jit cache compiles once per bucket signature.
+
+    Counts on the ``fabric.stage`` span ``sp`` the host ns spent building
+    the capacity table (``caps_ns``).
     """
     B = len(cases)
     n = cases[0][1].n
@@ -2914,11 +2919,13 @@ def _jax_batch_inputs(
     H = int(horizons.max())
     H_pad = _pad_to(H, _PAD_H)
 
+    t = time.perf_counter_ns()
     caps_list = [sched.capacity_per_slot(bits_per_slot)
                  for sched, _ in cases]
     ns = np.array([c.shape[0] for c in caps_list], dtype=np.int64)
     offs = np.concatenate([[0], np.cumsum(ns[:-1])])
     caps_flat = np.concatenate(caps_list, axis=0).astype(np.float32)
+    sp.add("caps_ns", time.perf_counter_ns() - t)
     cap_idx = np.zeros((H_pad, B), dtype=np.int32)
     cap_idx[:H] = offs[None, :] + (np.arange(H)[:, None] % ns[None, :])
     live = np.zeros((H_pad, B), dtype=np.float32)
@@ -3273,10 +3280,10 @@ def _twohop_batch_jax(
     with span("fabric.batch", kernel=name, B=B, n=n, H_pad=H_pad):
         with span("fabric.stage") as sp:
             caps_list, caps_flat, cap_idx, apos, asz, live, H = (
-                _jax_batch_inputs(cases, bits_per_slot))
+                _jax_batch_inputs(cases, bits_per_slot, sp))
             direct = np.array([0.0 if m == "vlb" else 1.0 for m in modes],
                               dtype=np.float32).reshape(B, 1, 1)
-            lut = (_sparse_plan_lut(caps_list, n, B, H, H_pad)
+            lut = (_sparse_plan_lut(caps_list, n, B, H, H_pad, sp)
                    if name == "twohop_sparse" else [])
             inputs = [caps_flat, cap_idx, apos, asz, live, *lut, direct]
             _staged(sp, *inputs)
@@ -3305,11 +3312,15 @@ def _twohop_batch_jax(
 
 
 def _sparse_plan_lut(caps_list, n: int, B: int, H: int,
-                     H_pad: int) -> list[np.ndarray]:
+                     H_pad: int, sp) -> list[np.ndarray]:
     """The ``twohop_sparse`` kernel's circuit-support LUT: one padded plan
     per distinct period-residue tuple (the same :class:`_SupportPlans`
     merge the NumPy engine uses) and each slot's index into it.  Returns
-    ``[plan_idx, p_row, p_v, p_b, p_valid]``."""
+    ``[plan_idx, p_row, p_v, p_b, p_valid]``.
+
+    Counts on the ``fabric.stage`` span ``sp`` the host ns spent here
+    (``lut_ns``)."""
+    t = time.perf_counter_ns()
     plans = _SupportPlans(caps_list, n, list(range(B)), B)
     keys: dict[tuple, int] = {}
     plan_idx = np.zeros(H_pad, dtype=np.int32)
@@ -3337,6 +3348,7 @@ def _sparse_plan_lut(caps_list, n: int, B: int, H: int,
         p_v[i, :j] = p["v"]
         p_b[i, :j] = p["b"]
         p_valid[i, :j] = True
+    sp.add("lut_ns", time.perf_counter_ns() - t)
     return [plan_idx, p_row, p_v, p_b, p_valid]
 
 
