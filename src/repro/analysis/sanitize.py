@@ -286,6 +286,16 @@ class Sanitizer:
             self._fail(name, "output port over-claimed in a slot "
                              "(not a partial matching)")
 
+    def check_caps_served(self, served: np.ndarray, caps: np.ndarray,
+                          label: str = "caps_served") -> None:
+        """The float32 capacity table a kernel was served is the float64
+        table ``caps`` rounded once, entry for entry."""
+        self._ran("caps_served")
+        if (served.shape != caps.shape
+                or not np.array_equal(served, caps.astype(np.float32))):
+            self._fail(label, "served float32 capacities are not the "
+                              "float64 table rounded once")
+
     # -- conservation / closure ---------------------------------------------
 
     def check_conservation(self, injected: float, delivered: float,

@@ -855,13 +855,16 @@ class _SupportPlans:
     Per (two-hop case, period slot), the <= n*d_hat (at, dst) pairs with
     nonzero capacity; relay drain/fill only ever touches these rows
     (everything else is an exact multiply-by-one / add-zero), so the
-    per-slot relay work is O(n^2 d_hat), not O(n^3).  ``tmap[b2]`` maps a
-    two-hop-local case index to its global batch index: ``row``/``bv``
-    (global) address the shared cap/voq/delivered tensors; ``row_l`` /
-    ``bv_l`` (local) address the relay tensor, which only exists for
-    two-hop cases.  The merged plan for a slot depends only on
-    ``slot % ns_b`` per case (the residue tuple :meth:`key`), so plans are
-    memoized on that tuple.
+    per-slot relay work is O(n^2 d_hat), not O(n^3).  ``supports[b2]``
+    holds case ``tmap[b2]``'s per-period-slot ``(at, v)`` support,
+    lex-sorted (what ``np.nonzero`` gives on its capacity table); cases on
+    one schedule may share it, since only the labels below depend on the
+    case.  ``tmap[b2]`` maps a two-hop-local case index to its global
+    batch index: ``row``/``bv`` (global) address the shared
+    cap/voq/delivered tensors; ``row_l`` / ``bv_l`` (local) address the
+    relay tensor, which only exists for two-hop cases.  The merged plan
+    for a slot depends only on ``slot % ns_b`` per case (the residue tuple
+    :meth:`key`), so plans are memoized on that tuple.
 
     One builder serves both backends: the NumPy relay loop consumes the
     memoized merged dicts (:meth:`plan`), the JAX backend densifies the
@@ -871,14 +874,13 @@ class _SupportPlans:
 
     _CAT = ("b", "row", "v", "bv", "row_l", "bv_l", "at")
 
-    def __init__(self, caps_list: list[np.ndarray], n: int,
-                 tmap: list[int], B: int):
-        self.ns = [caps_list[g].shape[0] for g in tmap]
+    def __init__(self, supports: list[list[tuple[np.ndarray, np.ndarray]]],
+                 n: int, tmap: list[int], B: int):
+        self.ns = [len(sup) for sup in supports]
         self.per_case: list[list[dict]] = []
-        for b2, g in enumerate(tmap):
+        for b2, (g, sup) in enumerate(zip(tmap, supports)):
             plans = []
-            for ps in range(caps_list[g].shape[0]):
-                at, v = np.nonzero(caps_list[g][ps])  # lex-sorted by (at, v)
+            for at, v in sup:
                 plans.append({
                     "J": len(at), "b": np.full(len(at), g),
                     "row": g * n + at, "v": v, "bv": g * n + v,
@@ -1166,7 +1168,9 @@ def _simulate_batch(
     tmap = [b for b, m in enumerate(modes) if m in ("rotorlb", "vlb")]
     two_hop = bool(tmap)
     if two_hop:
-        plan_for = _SupportPlans(caps_list, n, tmap, B).plan
+        plan_for = _SupportPlans(
+            [[np.nonzero(c) for c in caps_list[g]] for g in tmap],
+            n, tmap, B).plan
         direct_mask = np.array(
             [0.0 if m == "vlb" else 1.0 for m in modes])[:, None, None]
         all_direct = bool(np.all(direct_mask == 1.0))
@@ -2898,7 +2902,8 @@ def _jax_batch_inputs(
     cases: list[tuple[Schedule, Workload]], bits_per_slot: float, sp
 ):
     """Shared numpy-side prep for the jax engines: the periodic capacity
-    LUT, per-slot liveness, and padded per-slot arrival scatter lists.
+    LUT with each case's circuit support, per-slot liveness, and padded
+    per-slot arrival scatter lists.
 
     Horizon is padded to a ``_PAD_H`` bucket (padded slots carry zero
     capacity, zero liveness, and no arrivals — exact no-ops), arrivals per
@@ -2906,7 +2911,8 @@ def _jax_batch_inputs(
     so the jit cache compiles once per bucket signature.
 
     Counts on the ``fabric.stage`` span ``sp`` the host ns spent building
-    the capacity table (``caps_ns``).
+    the capacity table (``caps_ns``) and the distinct tables built
+    (``caps_tables``; see :func:`_caps_tables`).
     """
     B = len(cases)
     n = cases[0][1].n
@@ -2920,12 +2926,10 @@ def _jax_batch_inputs(
     H_pad = _pad_to(H, _PAD_H)
 
     t = time.perf_counter_ns()
-    caps_list = [sched.capacity_per_slot(bits_per_slot)
-                 for sched, _ in cases]
-    ns = np.array([c.shape[0] for c in caps_list], dtype=np.int64)
-    offs = np.concatenate([[0], np.cumsum(ns[:-1])])
-    caps_flat = np.concatenate(caps_list, axis=0).astype(np.float32)
+    caps_list, supports, caps_flat, offs, ns = _caps_tables(
+        [sched for sched, _ in cases], n, bits_per_slot)
     sp.add("caps_ns", time.perf_counter_ns() - t)
+    sp.add("caps_tables", len(np.unique(offs)))
     cap_idx = np.zeros((H_pad, B), dtype=np.int32)
     cap_idx[:H] = offs[None, :] + (np.arange(H)[:, None] % ns[None, :])
     live = np.zeros((H_pad, B), dtype=np.float32)
@@ -2952,7 +2956,48 @@ def _jax_batch_inputs(
     apos[rows_i, cols_i, 1] = f_src[order]
     apos[rows_i, cols_i, 2] = f_dst[order]
     asz[rows_i, cols_i] = f_size[order]
-    return caps_list, caps_flat, cap_idx, apos, asz, live, H
+    return caps_list, supports, caps_flat, cap_idx, apos, asz, live, H
+
+
+def _caps_tables(scheds: list[Schedule], n: int, bits_per_slot: float):
+    """The float32 periodic capacity table of a batch's schedules, built
+    once per distinct schedule: cases whose schedules have equal content
+    (planes, guard band, perms; not object identity) share rows.
+
+    Each table is filled from :meth:`Schedule.slot_circuits`, whose
+    parallel circuits accumulate in float64 and are rounded to float32
+    once on the write, so every row equals
+    ``capacity_per_slot(bits_per_slot).astype(np.float32)`` bit for bit.
+    Returns per case its table (a view into ``caps_flat``) and its
+    per-slot ``(at, v)`` support (the entries ``np.nonzero`` gives on the
+    float64 table), shared between cases of one schedule; then
+    ``caps_flat`` (the distinct tables only) and per case its row offset
+    and period."""
+    which, first, keys = [], [], {}
+    for sched in scheds:
+        p = sched.perms
+        key = (sched.d_hat, sched.recfg_frac, p.dtype.str, p.shape,
+               p.tobytes())
+        if key not in keys:
+            keys[key] = len(first)
+            first.append(sched)
+        which.append(keys[key])
+    ns = np.array([s.n_slots for s in first], dtype=np.int64)
+    offs = np.concatenate([[0], np.cumsum(ns[:-1])])
+    caps_flat = np.zeros((int(ns.sum()), n, n), dtype=np.float32)
+    rows = caps_flat.reshape(len(caps_flat), n * n)
+    views, supports = [], []
+    for k, sched in enumerate(first):
+        sup = []
+        for ps, (at, v, cap) in enumerate(
+                sched.slot_circuits(bits_per_slot)):
+            rows[offs[k] + ps, at * n + v] = cap
+            keep = cap != 0
+            sup.append((at[keep], v[keep]))
+        views.append(caps_flat[offs[k]:offs[k] + ns[k]])
+        supports.append(sup)
+    return ([views[k] for k in which], [supports[k] for k in which],
+            caps_flat, offs[which], ns[which])
 
 
 def _fetch(*outputs) -> list[np.ndarray]:
@@ -3000,15 +3045,19 @@ def _sanitize_jax_batch(
     voq_f: np.ndarray, relay_queued: np.ndarray | None = None,
 ) -> None:
     """Shared post-run sanitizer pass for the jax engines: entry contracts
-    plus per-case float32 bit conservation from the kernels' final carry."""
+    (each case's float64 capacity table, and the float32 table it was
+    served, ``caps_list[b]``, that table rounded once) plus per-case
+    float32 bit conservation from the kernels' final carry."""
     n = cases[0][1].n
     for b, (sched, wl) in enumerate(cases):
         san.check_workload(wl)
         san.check_schedule(sched)
+        caps = sched.capacity_per_slot(bits_per_slot)
         san.check_caps_dense(
-            caps_list[b], sched.d_hat,
-            bits_per_slot * (1.0 - sched.recfg_frac),
+            caps, sched.d_hat, bits_per_slot * (1.0 - sched.recfg_frac),
             label=f"jax:case{b}:caps")
+        san.check_caps_served(caps_list[b], caps,
+                              label=f"jax:case{b}:caps_served")
         queued = float(voq_f[b].sum())
         if relay_queued is not None:
             queued += float(relay_queued[b])
@@ -3279,11 +3328,11 @@ def _twohop_batch_jax(
     name = "twohop_fct" if fct_path else f"twohop_{kernel}"
     with span("fabric.batch", kernel=name, B=B, n=n, H_pad=H_pad):
         with span("fabric.stage") as sp:
-            caps_list, caps_flat, cap_idx, apos, asz, live, H = (
+            caps_list, supports, caps_flat, cap_idx, apos, asz, live, H = (
                 _jax_batch_inputs(cases, bits_per_slot, sp))
             direct = np.array([0.0 if m == "vlb" else 1.0 for m in modes],
                               dtype=np.float32).reshape(B, 1, 1)
-            lut = (_sparse_plan_lut(caps_list, n, B, H, H_pad, sp)
+            lut = (_sparse_plan_lut(supports, n, B, H, H_pad, sp)
                    if name == "twohop_sparse" else [])
             inputs = [caps_flat, cap_idx, apos, asz, live, *lut, direct]
             _staged(sp, *inputs)
@@ -3311,17 +3360,18 @@ def _twohop_batch_jax(
     return results
 
 
-def _sparse_plan_lut(caps_list, n: int, B: int, H: int,
+def _sparse_plan_lut(supports, n: int, B: int, H: int,
                      H_pad: int, sp) -> list[np.ndarray]:
     """The ``twohop_sparse`` kernel's circuit-support LUT: one padded plan
     per distinct period-residue tuple (the same :class:`_SupportPlans`
-    merge the NumPy engine uses) and each slot's index into it.  Returns
+    merge the NumPy engine uses, over the per-case ``supports`` of
+    :func:`_caps_tables`) and each slot's index into it.  Returns
     ``[plan_idx, p_row, p_v, p_b, p_valid]``.
 
     Counts on the ``fabric.stage`` span ``sp`` the host ns spent here
     (``lut_ns``)."""
     t = time.perf_counter_ns()
-    plans = _SupportPlans(caps_list, n, list(range(B)), B)
+    plans = _SupportPlans(supports, n, list(range(B)), B)
     keys: dict[tuple, int] = {}
     plan_idx = np.zeros(H_pad, dtype=np.int32)
     plan_list: list[dict] = []

@@ -2,7 +2,9 @@
 batches: the capacity table's time on dense and sparse batches, the support
 lookup table's time on sparse batches only (with the lookup table's plans
 and support they time), and kernel inputs and results unchanged by the
-counting."""
+counting.  A batch stages each distinct schedule once (``caps_tables``):
+its rows, lookup table and kernel outputs are those of one float64 table
+per case."""
 from contextlib import contextmanager
 
 import numpy as np
@@ -29,6 +31,10 @@ def _cases(n=13, d_hats=(4, 3), horizon=60, seed=5):
             for d in d_hats]
 
 
+def _modes(cases):
+    return [("rotorlb", "vlb")[b % 2] for b in range(len(cases))]
+
+
 def _run(cases, kernel):
     """Serve ``cases`` on ``kernel``; returns the results, the stage
     span's record and the arrays handed to the kernel."""
@@ -42,7 +48,7 @@ def _run(cases, kernel):
 
     fns[name] = spy
     try:
-        res = sim._twohop_batch_jax(cases, BPS, ["rotorlb", "vlb"],
+        res = sim._twohop_batch_jax(cases, BPS, _modes(cases),
                                     kernel=kernel)
     finally:
         fns[name] = real
@@ -112,3 +118,138 @@ def test_counting_leaves_inputs_and_results_bit_identical(kernel,
         assert r.delivered_bits == b.delivered_bits
         assert r.avg_hops == b.avg_hops
         assert r.utilization == b.utilization
+
+
+N4 = 13
+
+
+def _four(shared: bool):
+    """Two loads x rotorlb / vlb on d_hat = 4 oblivious schedules (as a
+    two-hop cell's request): one schedule object for all four cases, or
+    one built per case (equal content, distinct objects)."""
+    wls = [websearch_workload(N4, load, 60, BPS, d_hat=2, seed=5)
+           for load in (0.3, 0.6)]
+    one = oblivious_schedule(N4, d_hat=4, recfg_frac=RECFG)
+    return [(one if shared else
+             oblivious_schedule(N4, d_hat=4, recfg_frac=RECFG), wl)
+            for wl in wls for _ in range(2)]
+
+
+# batch, expected capacity-table rows (a d_hat = 4 period is 3 slots at
+# n = 13), expected distinct tables
+BATCHES = {"shared": (lambda: _four(True), 3, 1),
+           "equal": (lambda: _four(False), 3, 1),
+           "mixed": (_cases, 3 + 4, 2)}
+
+
+def _per_case_caps(cases):
+    """The capacity table as one float64 table per case: the rows
+    concatenated and cast to float32, each case's row offsets, and each
+    case's float64 table."""
+    caps64 = [s.capacity_per_slot(BPS) for s, _ in cases]
+    ns = np.array([c.shape[0] for c in caps64])
+    offs = np.concatenate([[0], np.cumsum(ns[:-1])])
+    return (np.concatenate(caps64).astype(np.float32), offs, ns, caps64)
+
+
+def _per_case_lut(caps64, H, H_pad):
+    """The sparse lookup table built from one float64 table per case."""
+    return sim._sparse_plan_lut(
+        [[np.nonzero(c) for c in caps] for caps in caps64], N4,
+        len(caps64), H, H_pad, _Null())
+
+
+@pytest.mark.parametrize("batch", sorted(BATCHES))
+@pytest.mark.parametrize("kernel", ["dense", "sparse"])
+def test_each_distinct_schedule_is_staged_once(kernel, batch):
+    make, rows, tables = BATCHES[batch]
+    cases = make()
+    _, stage, inputs = _run(cases, kernel)
+    caps_flat, cap_idx = inputs[0], inputs[1]
+    assert caps_flat.dtype == np.float32
+    assert caps_flat.shape == (rows, N4, N4)
+    assert stage.attrs["caps_tables"] == tables
+    H = max(wl.horizon for _, wl in cases)
+    for b, (s, _) in enumerate(cases):
+        want = s.capacity_per_slot(BPS).astype(np.float32)
+        for slot in range(H):
+            got = caps_flat[cap_idx[slot, b]]
+            assert (got.tobytes()
+                    == want[slot % s.n_slots].tobytes()), (b, slot)
+
+
+@pytest.mark.parametrize("batch", sorted(BATCHES))
+@pytest.mark.parametrize("kernel", ["dense", "sparse"])
+def test_kernel_outputs_match_per_case_tables(kernel, batch):
+    cases = BATCHES[batch][0]()
+    _, _, inputs = _run(cases, kernel)
+    caps_flat, offs, ns, caps64 = _per_case_caps(cases)
+    H = max(wl.horizon for _, wl in cases)
+    cap_idx = np.zeros_like(inputs[1])
+    cap_idx[:H] = offs[None, :] + (np.arange(H)[:, None] % ns[None, :])
+    per_case = [caps_flat, cap_idx, *inputs[2:]]
+    if kernel == "sparse":
+        per_case[5:10] = _per_case_lut(caps64, H, len(cap_idx))
+    fn = sim._jax_fns()[f"twohop_{kernel}"]
+    (delivered, second), carry = fn(*inputs)
+    (delivered0, second0), carry0 = fn(*per_case)
+    for a, b in [(delivered, delivered0), (second, second0),
+                 *zip(carry, carry0)]:
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("batch", sorted(BATCHES))
+def test_lookup_table_matches_per_case_tables(batch):
+    cases = BATCHES[batch][0]()
+    _, _, inputs = _run(cases, "sparse")
+    caps64 = _per_case_caps(cases)[3]
+    H = max(wl.horizon for _, wl in cases)
+    want = _per_case_lut(caps64, H, len(inputs[5]))
+    for got, ref in zip(inputs[5:10], want):
+        assert got.dtype == ref.dtype and np.array_equal(got, ref)
+
+
+@pytest.mark.parametrize("kernel", [None, "dense", "sparse"])
+def test_sanitizer_checks_a_float64_table_per_case(kernel, monkeypatch):
+    from repro.analysis.sanitize import Sanitizer
+    cases = _four(True)
+    seen = []
+    real = Sanitizer.check_caps_dense
+
+    def spy(self, caps, *args, **kw):
+        seen.append((caps.dtype, caps.shape))
+        return real(self, caps, *args, **kw)
+
+    monkeypatch.setattr(Sanitizer, "check_caps_dense", spy)
+    san = Sanitizer()
+    res = sim._twohop_batch_jax(cases, BPS, _modes(cases), kernel=kernel,
+                                san=san)
+    assert len(res) == 4
+    ns = cases[0][0].n_slots
+    assert seen == [(np.float64, (ns, N4, N4))] * 4
+    assert san.counts["caps_served"] == 4
+
+
+def test_sanitizer_refuses_a_served_table_off_by_one_ulp():
+    from repro.analysis.sanitize import SanitizeError, Sanitizer
+    s = _four(True)[0][0]
+    caps = s.capacity_per_slot(BPS)
+    served = caps.astype(np.float32)
+    Sanitizer().check_caps_served(served, caps)
+    served[0][caps[0] > 0] = np.nextafter(served[0][caps[0] > 0], 0)
+    with pytest.raises(SanitizeError):
+        Sanitizer().check_caps_served(served, caps)
+
+
+def test_numpy_batch_on_one_schedule_is_unchanged():
+    shared, equal = _four(True), _four(False)
+    modes = _modes(shared)
+    got = sim._simulate_batch(shared, BPS, modes)
+    same = sim._simulate_batch(equal, BPS, modes)
+    for (s, wl), m, r, e in zip(shared, modes, got, same):
+        assert np.array_equal(r.fct_slots, e.fct_slots)
+        assert r.delivered_bits == e.delivered_bits
+        assert r.avg_hops == e.avg_hops
+        ref = sim.simulate_reference(s, wl, BPS, mode=m)
+        assert np.array_equal(r.fct_slots, ref.fct_slots)
+        assert np.isclose(r.delivered_bits, ref.delivered_bits, rtol=1e-6)
