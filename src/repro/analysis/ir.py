@@ -1,7 +1,7 @@
 """IR-level kernel analyzer: static jaxpr accounting for the scan kernels.
 
 The AST lint (:mod:`repro.analysis.lint`) sees *source*; since the hot path
-became five jitted ``lax.scan`` kernels, the structures that matter — a
+became four jitted ``lax.scan`` kernels, the structures that matter — a
 dense ``(B, n, n)`` intermediate materialized inside a scan body, a float64
 promotion surviving tracing, a carry that silently grew a dimension — only
 exist post-tracing.  This module traces every cached kernel with the same

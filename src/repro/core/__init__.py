@@ -58,7 +58,6 @@ from .simulator import (
     simulate,
     run_sweep,
     run_adaptive,
-    simulate_aggregate_jax,
 )
 from .estimation import (
     RingViews,

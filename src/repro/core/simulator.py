@@ -74,23 +74,19 @@ through one slot loop with a leading batch axis:
 
 Backend selection
 =================
-``backend="numpy"`` (default) is exact f64, supports every feature —
-faults, repair, ``collision="fullest"``, activation jitter, ``measured``
-construction charging — and wins on one-off small grids where jit
-compilation would dominate.  ``backend="jax"`` serves in f32 on the
-accelerator and wins on repeated or wide grids (same padded shape →
-compile once, then several-times-faster slot loops; the adaptive
-disagreement sweep drops from minutes to seconds): both :func:`run_sweep`
-and :func:`run_adaptive` accept it, and both emit per-flow FCT
-percentiles (two-hop modes only up to ``_TWOHOP_FCT_MAX_N``).  The jax
-adaptive path replays the control plane host-side (decision-identical to
-numpy — the epoch counters are arrivals-only) and batches every case's
-serving through ONE device scan; configurations needing per-slot host
-decisions inside the serving loop stay NumPy-only: fault injection
-raises ``NotImplementedError``, and repair / ``fullest`` / jitter raise
-``ValueError``.  Aggregates match
+``backend="numpy"`` (default) is the exact f64 reference and the only
+path for faults, repair, ``collision="fullest"`` and activation jitter,
+whose decisions read what was served.  ``backend="jax"`` serves in f32
+on the device; :func:`run_sweep` and :func:`run_adaptive` both accept
+it, and both emit per-flow FCTs (two-hop modes only up to
+``_TWOHOP_FCT_MAX_N``).  The jax adaptive path steps the same control
+object as the numpy loop, compiles each case's whole plan before serving
+(the epoch counters are arrivals-only) and serves every case in ONE
+device scan; the numpy-only features raise on it up front (faults
+``NotImplementedError``, the rest ``ValueError``).  Aggregates match
 numpy to f32 tolerance (~1e-3 relative); FCTs match exactly on
-well-conditioned instances.
+well-conditioned instances.  Chip timings are in ``PERF_LEDGER.jsonl``;
+it has none for the adaptive path.
 
 6. **Adaptive epoch layer.**  :func:`run_adaptive` (see
    :class:`AdaptiveCase`) closes the paper's estimation→schedule control
@@ -110,8 +106,10 @@ well-conditioned instances.
    Construction is optionally charged for real
    (``AdaptiveCase.construction_slots``): the new schedule only
    activates after the slots its construction consumed, with the stale
-   schedule serving in the interim.  :func:`phase_shifting_workload`
-   generates the non-stationary (phase-train) traffic that exercises it.
+   schedule serving in the interim.  One ``_AdaptiveControl`` per case
+   makes every epoch decision; both backends' serving loops step it.
+   :func:`phase_shifting_workload` generates the non-stationary
+   (phase-train) traffic that exercises it.
 
 7. **Fault injection & degraded service.**  A timed
    :class:`repro.core.faults.FaultSchedule` threads failures through the
@@ -209,7 +207,6 @@ __all__ = [
     "simulate_reference",
     "run_sweep",
     "run_adaptive",
-    "simulate_aggregate_jax",
     "compile_cache_stats",
     "WEBSEARCH_CDF",
 ]
@@ -1916,8 +1913,8 @@ class AdaptiveRow:
     construction_s: float = 0.0     # the modelled construction charge
                                     # (summed over all unique per-node
                                     # views), not a layer time: on the jax
-                                    # path a sched_cache hit adds the
-                                    # cached construction's time again
+                                    # path a construction cache hit adds
+                                    # the cached construction's time again
     dark_slots: int = 0             # slots lost to reconfiguration darkness
                                     # (reconfig_penalty_slots per hot-swap)
     epoch_disagreement: np.ndarray = None   # type: ignore[assignment]
@@ -1944,127 +1941,388 @@ class AdaptiveRow:
     excised_planes: int = 0         # planes the repair loop excised
 
 
-def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
-                       san=None) -> AdaptiveRow:
-    if case.policy not in _POLICIES:
-        raise ValueError(case.policy)
-    if case.epoch_slots <= 0:
-        raise ValueError("epoch_slots must be positive")
-    cs = case.construction_slots
-    measured = cs == "measured"
-    if not measured and not (isinstance(cs, (int, np.integer)) and cs >= 0):
-        raise ValueError(
-            "construction_slots must be a nonnegative int or 'measured'")
-    if measured and case.slot_seconds <= 0:
-        raise ValueError("slot_seconds must be positive")
-    penalty = int(case.reconfig_penalty_slots)
-    if penalty < 0:
-        raise ValueError("reconfig_penalty_slots must be nonnegative")
-    if case.collision not in _COLLISIONS:
-        raise ValueError(f"collision must be one of {_COLLISIONS} "
-                         f"(got {case.collision!r})")
-    wl, n = case.wl, case.wl.n
-    E, H = case.epoch_slots, wl.horizon
-    n_epochs = -(-H // E)
-    if san is not None:
-        # any violation below names the offending case of the grid
-        san.set_context(f"case={case.label}")
-        san.check_workload(wl)
-    san_w = bits_per_slot * (1.0 - case.recfg_frac)
+class _AdaptiveControl:
+    """The adaptive control plane of one :class:`AdaptiveCase`: the epoch
+    decision that both serving loops step.
 
-    # flow state shared across epochs — a schedule hot-swap never resets it
-    pid = (wl.src * n + wl.dst).astype(np.int64)
-    f_size = wl.size.astype(np.float64)
-    fct = np.full(wl.num_flows, np.inf)
-    credit = _CreditState(n * n, pid, f_size, wl.arrival, fct)
-    valid = wl.arrival < H
-    order = np.argsort(wl.arrival, kind="stable")
-    order = order[valid[order]]
-    bucket = np.searchsorted(wl.arrival[order], np.arange(H + 1))
-    voq = np.zeros(n * n)
+    A loop calls :meth:`begin_slot` at every slot it serves and, when that
+    returns True, :meth:`epoch_boundary`; between them it serves ``fp``
+    from ``sched_t0`` with the planes of ``plane_dark_until`` dark and,
+    while ``transition`` is open (activation jitter), the mixed old/new
+    generations.  :func:`_run_adaptive_case` feeds the boundary the
+    counters it accumulated from accepted arrivals and, under ``repair``,
+    the data plane's NACK counts; :func:`_compile_adaptive_plan` feeds it
+    no counters, and they are rebuilt from the epoch's arrival slice — the
+    same bits, since counters accumulate arrivals only.
 
-    # true per-epoch offered matrices (oracle policy + estimate-error
-    # metric); dense by design: the O(n^2) control plane owns these
-    true_epoch = np.zeros((n_epochs, n, n))  # lint: allow-dense
-    np.add.at(true_epoch,
-              (wl.arrival[order] // E, wl.src[order], wl.dst[order]),
-              f_size[order])
-    oracle_m = case.oracle_demand
-    if oracle_m is not None and oracle_m.shape != (n_epochs, n, n):
-        raise ValueError(
-            f"oracle_demand shape {oracle_m.shape} != {(n_epochs, n, n)}")
-    if oracle_m is None:
-        oracle_m = true_epoch / E
+    ``cache`` is a batch's memo shared across its cases: schedule
+    construction keyed on the exact estimator inputs (a hit charges the
+    cached build's time again), and — without a sanitizer — each epoch's
+    estimation round keyed on the workload object and the estimator's
+    parameters (the fleet EWMA is stateful, so a case either hits every
+    epoch of a cached trajectory or replays the whole chain itself).
+    Unused for ``construction_slots="measured"``, whose charge is the wall
+    clock of a fresh build.
+    """
 
-    # per-node VOQ byte counters, accumulated over the running epoch (A2);
-    # one fleet estimator batches all n per-node EWMAs (row i = node i)
-    counters = np.zeros((n, n))
-    fleet = TrafficEstimator.fleet(n, alpha=case.alpha)
-    q_unit = _quantizer_unit(E, case.k, case.d_hat, bits_per_slot)
-
-    construction_s = 0.0
-    last_construction = 0.0
-
-    def consistent_plan(sched: Schedule,
-                        plane_map: np.ndarray | None = None) -> _FabricPlan:
-        fp = _fabric_plan([sched], np.zeros(n, dtype=np.int64),
-                          bits_per_slot, case.collision, plane_map=plane_map)
+    def __init__(self, case: AdaptiveCase, bits_per_slot: float, san=None,
+                 cache: dict | None = None):
+        wl, n = case.wl, case.wl.n
+        E, H = case.epoch_slots, wl.horizon
+        self.case, self.n, self.san = case, n, san
+        self.bits_per_slot = bits_per_slot
+        self.n_epochs = n_epochs = -(-H // E)
+        self.measured = case.construction_slots == "measured"
+        self.cache = None if self.measured else cache
         if san is not None:
-            san.check_schedule(sched)
-            san.check_fabric_plan(fp, n, sched.d_hat, san_w)
+            # any violation below names the offending case of the grid
+            san.set_context(f"case={case.label}")
+            san.check_workload(wl)
+        self.san_w = bits_per_slot * (1.0 - case.recfg_frac)
+
+        self.f_size = f_size = wl.size.astype(np.float64)
+        valid = wl.arrival < H
+        order = np.argsort(wl.arrival, kind="stable")
+        self.order = order = order[valid[order]]
+        self.bucket = np.searchsorted(wl.arrival[order], np.arange(H + 1))
+        # true per-epoch offered matrices (oracle policy + estimate-error
+        # metric); dense by design: the O(n^2) control plane owns these
+        self.true_epoch = np.zeros((n_epochs, n, n))  # lint: allow-dense
+        np.add.at(self.true_epoch,
+                  (wl.arrival[order] // E, wl.src[order], wl.dst[order]),
+                  f_size[order])
+        oracle_m = case.oracle_demand
+        if oracle_m is not None and oracle_m.shape != (n_epochs, n, n):
+            raise ValueError(
+                f"oracle_demand shape {oracle_m.shape} != {(n_epochs, n, n)}")
+        self.oracle_m = self.true_epoch / E if oracle_m is None else oracle_m
+        # one fleet estimator batches all n per-node EWMAs (row i = node i)
+        self.fleet = TrafficEstimator.fleet(n, alpha=case.alpha)
+        self.q_unit = _quantizer_unit(E, case.k, case.d_hat, bits_per_slot)
+        self.key_base = (case.k, case.d_hat, case.recfg_frac, case.normalize,
+                         case.method)
+
+        self.construction_s = 0.0       # fabric-wide construction charge
+        self.last_construction = 0.0    # one local construction, the latest
+        self.est_tv = np.full(n_epochs, np.nan)
+        self.recomputes = 0
+        self.groups_max = 1
+        self.pending: tuple[int, _FabricPlan] | None = None
+        self.plane_dark_until = np.zeros(case.d_hat, dtype=np.int64)
+        self.act_rng = np.random.default_rng([abs(int(case.seed)), 0xAC7])
+        # (old_fp, old_t0, per-node activation slots, end slot) while a
+        # jittered swap is mid-transition, else None
+        self.transition: tuple[_FabricPlan, int, np.ndarray, int] | None = None
+        # repair-loop detection state
+        self.tx_silent = np.zeros(n, dtype=np.int64)  # consecutive silent
+        self.excised_tx = np.zeros(n, dtype=bool)
+        self.excised_rx = np.zeros(n, dtype=bool)
+        self.plane_alive = np.ones(case.d_hat, dtype=bool)
+        # churn hysteresis: normalized estimate + repair state at last build
+        self.last_est: np.ndarray | None = None
+        self.last_sig: tuple | None = None
+
+        if case.policy in ("oracle", "stale"):
+            self.fp = self._consistent_plan(
+                self._vsched(self.oracle_m[0], case.seed))
+        else:  # adaptive cold start (no estimate yet) and oblivious baseline
+            self.fp = self._consistent_plan(oblivious_schedule(
+                n, d_hat=case.d_hat, recfg_frac=case.recfg_frac))
+        self.sched_t0 = 0               # slot the current plan was installed
+
+    def _built(self, key: tuple | None, build):
+        """``(build(), seconds)``, memoized in ``cache`` under ``key``
+        (never under None)."""
+        cache = self.cache if key is not None else None
+        hit = cache.get(key) if cache is not None else None
+        if hit is None:
+            t0 = time.perf_counter()
+            hit = (build(), time.perf_counter() - t0)
+            if cache is not None:
+                cache[key] = hit
+        return hit
+
+    def _consistent_plan(self, sched: Schedule) -> _FabricPlan:
+        fp = _fabric_plan([sched], np.zeros(self.n, dtype=np.int64),
+                          self.bits_per_slot, self.case.collision)
+        if self.san is not None:
+            self.san.check_schedule(sched)
+            self.san.check_fabric_plan(fp, self.n, sched.d_hat, self.san_w)
         return fp
 
-    def vsched(m: np.ndarray, seed: int) -> Schedule:
-        nonlocal construction_s, last_construction
-        t0 = time.perf_counter()
-        s = vermilion_schedule(
-            m, k=case.k, d_hat=case.d_hat, recfg_frac=case.recfg_frac,
-            seed=seed, normalize=case.normalize, method=case.method)
-        last_construction = time.perf_counter() - t0
-        construction_s += last_construction
+    def _vsched(self, m: np.ndarray, seed: int) -> Schedule:
+        c = self.case
+        s, self.last_construction = self._built(
+            ("v", m.tobytes(), seed) + self.key_base,
+            lambda: vermilion_schedule(
+                m, k=c.k, d_hat=c.d_hat, recfg_frac=c.recfg_frac, seed=seed,
+                normalize=c.normalize, method=c.method))
+        self.construction_s += self.last_construction
         return s
 
-    def vsched_per_node(views, seed: int, unique, d_hat: int | None = None,
-                        plane_map: np.ndarray | None = None) -> _FabricPlan:
-        nonlocal construction_s, last_construction
-        dh = case.d_hat if d_hat is None else d_hat
-        t0 = time.perf_counter()
-        scheds, owner = per_node_schedules(
-            views, k=case.k, d_hat=dh, recfg_frac=case.recfg_frac,
-            seed=seed, normalize=case.normalize, method=case.method,
-            unique=unique)
-        dt = time.perf_counter() - t0
-        construction_s += dt
+    def _vsched_per_node(self, views, seed: int, unique, d_hat: int,
+                         plane_map: np.ndarray | None = None) -> _FabricPlan:
+        c = self.case
+        masks, owner = unique
+        (scheds, sowner), dt = self._built(
+            ("pn", views.rows.tobytes(), masks.tobytes(), owner.tobytes(),
+             seed, d_hat) + self.key_base,
+            lambda: per_node_schedules(
+                views, k=c.k, d_hat=d_hat, recfg_frac=c.recfg_frac,
+                seed=seed, normalize=c.normalize, method=c.method,
+                unique=unique))
+        self.construction_s += dt
         # every ToR builds only its own schedule, all concurrently: the
         # fabric waits for one local construction, estimated as the mean
         # over the (equal-sized) unique views rather than the sum (with a
         # complete gather there is exactly one view, so this reduces to
         # the single-schedule charge exactly)
-        last_construction = dt / len(scheds)
-        fp = _fabric_plan(scheds, owner, bits_per_slot, case.collision,
+        self.last_construction = dt / len(scheds)
+        fp = _fabric_plan(scheds, sowner, self.bits_per_slot, c.collision,
                           plane_map=plane_map)
-        if san is not None:
-            for s in scheds:       # pre-merge: every row a permutation
-                san.check_schedule(s)
-            san.check_fabric_plan(fp, n, dh, san_w)
+        if self.san is not None:
+            for s in scheds:           # pre-merge: every row a permutation
+                self.san.check_schedule(s)
+            self.san.check_fabric_plan(fp, self.n, d_hat, self.san_w)
         return fp
 
-    if case.policy in ("oracle", "stale"):
-        fp = consistent_plan(vsched(oracle_m[0], case.seed))
-    else:  # adaptive cold start (no estimate yet) and oblivious baseline
-        fp = consistent_plan(oblivious_schedule(n, d_hat=case.d_hat,
-                                                recfg_frac=case.recfg_frac))
-    sched_t0 = 0                    # slot the current plan was installed
-    pending: tuple[int, _FabricPlan] | None = None
+    def _activate(self, new_fp: _FabricPlan, s: int) -> None:
+        """Install a newly built plan at slot ``s``: darken only the
+        planes whose matchings actually changed, and (under activation
+        jitter) open the mixed old/new transition window."""
+        c, fp = self.case, self.fp
+        penalty, jit = (int(c.reconfig_penalty_slots),
+                        int(c.activation_jitter_slots))
+        if penalty:
+            om, nm = fp.plane_map, new_fp.plane_map
+            if (fp.eff is None or new_fp.eff is None
+                    or fp.eff.shape != new_fp.eff.shape
+                    or not np.array_equal(om, nm)):
+                self.plane_dark_until[nm] = s + penalty  # all retarget
+            else:
+                ch = planes_changed(fp.eff, new_fp.eff, len(nm))
+                self.plane_dark_until[nm[ch]] = s + penalty
+        if jit:
+            act = s + self.act_rng.integers(0, jit + 1, size=self.n)
+            self.transition = (fp, self.sched_t0, act, s + jit + 1)
+        self.fp, self.sched_t0 = new_fp, s
+        self.groups_max = max(self.groups_max, new_fp.groups)
 
-    delivered_ep = np.zeros(n_epochs)
-    est_tv = np.full(n_epochs, np.nan)
-    dis_ep = np.zeros(n_epochs)     # summed per-slot plan disagreement
-    coll_ep = np.zeros(n_epochs)    # bits of capacity lost to collisions
-    recomputes = 0
+    def begin_slot(self, slot: int) -> bool:
+        """Install a pending plan that is due and close an ended transition
+        window; True when ``slot`` opens an epoch."""
+        if self.pending is not None and slot >= self.pending[0]:
+            new_fp, self.pending = self.pending[1], None
+            self._activate(new_fp, slot)
+        if self.transition is not None and slot >= self.transition[3]:
+            self.transition = None
+        boundary = slot > 0 and slot % self.case.epoch_slots == 0
+        if boundary and self.san is not None:
+            self.san.set_context(f"case={self.case.label} "
+                                 f"epoch={slot // self.case.epoch_slots} "
+                                 f"slot={slot}")
+        return boundary
+
+    def _detect_failures(self, counters: np.ndarray, repair_obs) -> None:
+        """Repair's detection from one epoch of observations."""
+        # dead senders: gather rows silent for repair_after_epochs
+        # consecutive epochs (the fleet EWMA would otherwise keep
+        # allocating circuits to a row that stopped refreshing)
+        silent = counters.sum(axis=1) <= 0.0
+        self.tx_silent[:] = np.where(silent, self.tx_silent + 1, 0)
+        self.excised_tx |= self.tx_silent >= self.case.repair_after_epochs
+        # dead receivers / planes: the data plane counts wanting circuits
+        # whose far side never carried (fault-masked) as NACKs; a
+        # near-total NACK ratio flags the target.  A dead plane NACKs ~all
+        # its claims, a dead ToR ~all claims toward it on every plane; a
+        # single dead port sits at ~1/d_hat on both counters and is left
+        # in place (degraded service, no excision).
+        rx_want, rx_nack, plane_want, plane_nack = repair_obs
+        self.excised_rx |= (rx_want > 10) & (rx_nack > 0.9 * rx_want)
+        self.plane_alive &= ~((plane_want > 10)
+                              & (plane_nack > 0.9 * plane_want))
+
+    def _estimate(self, epoch: int, counters: np.ndarray | None):
+        """The epoch's estimation round: every node's view, the unique
+        views, and the estimate-vs-truth TV metric (None: no estimate)."""
+        c = self.case
+        if counters is None:
+            # counters accumulate arrivals only: one np.add.at over the
+            # epoch's stable-ordered arrival slice performs the serving
+            # loop's per-slot accumulation element for element
+            E = c.epoch_slots
+            seg = self.order[self.bucket[(epoch - 1) * E]:
+                             self.bucket[epoch * E]]
+            counters = np.zeros((self.n, self.n))
+            np.add.at(counters, (c.wl.src[seg], c.wl.dst[seg]),
+                      self.f_size[seg])
+        views = estimate_all_views(counters, self.fleet, c.k, self.q_unit,
+                                   steps=c.gather_steps)
+        if self.san is not None:
+            self.san.check_views(views)
+        if c.repair and (self.excised_tx.any() or self.excised_rx.any()):
+            # excise failed senders/receivers from the estimate so the
+            # rebuild allocates their capacity to healthy ports
+            views = views.excise(self.excised_tx, self.excised_rx)
+        t = self.true_epoch[epoch - 1]
+        masks, owner = views.unique()
+        # estimate error: per-node TV distance vs the epoch truth, averaged
+        # over nodes (one term per unique view, weighted by its group size
+        # — a complete gather has one group and reduces to the historical
+        # single-estimate metric).  The per-view normalizations differ, so
+        # the metric is inherently O(G n^2); G == 1 on the consistent path,
+        # and under full disagreement (G == n) schedule construction
+        # already dominates this same order of work.
+        counts = np.bincount(owner, minlength=masks.shape[0])
+        t_sum = t.sum()
+        tn = t / t_sum if t_sum > 0 else None
+        # cheap emptiness predicate per group (exact for nonnegative rows);
+        # the actual normalizer below keeps the historical full-matrix
+        # summation order bit-for-bit
+        nonempty = (masks @ views.rows.sum(axis=1)) > 0
+        tvs, wts = [], []
+        for g in range(masks.shape[0]):
+            if tn is not None and nonempty[g]:
+                est_g = views.rows * masks[g][:, None]
+                tvs.append(0.5 * np.abs(est_g / est_g.sum() - tn).sum())
+                wts.append(counts[g])
+        tv = float(np.average(tvs, weights=wts)) if tvs else None
+        return views, masks, owner, tv
+
+    def epoch_boundary(self, slot: int, counters: np.ndarray | None = None,
+                       repair_obs=None) -> None:
+        """The decision at the boundary ``slot``: repair detection, the
+        estimation round, churn hysteresis, the build, its construction
+        charge, and the swap now or ``pending`` until the charge is paid.
+
+        ``counters``: the finished epoch's per-node VOQ byte counters
+        (None: rebuilt from its arrival slice when the estimation round is
+        not cached).  ``repair_obs``: the data plane's ``(rx_want,
+        rx_nack, plane_want, plane_nack)`` counts over the epoch, read
+        under ``repair``."""
+        c = self.case
+        epoch = slot // c.epoch_slots
+        if c.repair:
+            self._detect_failures(counters, repair_obs)
+        swap = None
+        if c.policy == "adaptive":
+            # the round and its TV metric are collision-independent, so a
+            # grid varying only the data-plane resolution computes them once
+            ctl_key = None if self.san is not None else (
+                ("ctl", id(c.wl), epoch, c.gather_steps, c.alpha,
+                 c.epoch_slots, c.seed) + self.key_base)
+            (views, masks, owner, tv), _ = self._built(
+                ctl_key, lambda: self._estimate(epoch, counters))
+            if tv is not None:
+                self.est_tv[epoch - 1] = tv
+            build = views.rows.sum() > 0
+            if build and c.swap_tv_threshold > 0.0:
+                # churn hysteresis: skip the rebuild while the estimate
+                # hasn't materially moved and the repair state (excisions,
+                # surviving planes) is unchanged — a converged stationary
+                # estimate stops paying the reconfiguration dark window
+                cur = views.rows / views.rows.sum()
+                sig = (self.plane_alive.tobytes(), self.excised_tx.tobytes(),
+                       self.excised_rx.tobytes())
+                if (self.last_est is not None and sig == self.last_sig
+                        and 0.5 * np.abs(cur - self.last_est).sum()
+                            < c.swap_tv_threshold):
+                    build = False
+                else:
+                    self.last_est, self.last_sig = cur, sig
+            if build and self.plane_alive.all():
+                swap = self._vsched_per_node(views, c.seed + epoch,
+                                             (masks, owner), c.d_hat)
+            elif build and self.plane_alive.any():
+                # rebuild over the surviving planes
+                swap = self._vsched_per_node(
+                    views, c.seed + epoch, (masks, owner),
+                    int(self.plane_alive.sum()),
+                    plane_map=np.nonzero(self.plane_alive)[0])
+        elif c.policy == "oracle" and self.oracle_m[epoch].sum() > 0:
+            swap = self._consistent_plan(
+                self._vsched(self.oracle_m[epoch], c.seed + epoch))
+        if swap is not None:
+            self.recomputes += 1
+            charge = (int(np.ceil(self.last_construction / c.slot_seconds))
+                      if self.measured else int(c.construction_slots))
+            if charge == 0:
+                self.pending = None   # a zero-cost swap supersedes any pending
+                self._activate(swap, slot)
+            else:
+                # the stale schedule keeps serving until construction
+                # finishes; a recompute next epoch supersedes this one
+                self.pending = (slot + charge, swap)
+
+    def row(self, fct: np.ndarray, delivered_ep: np.ndarray,
+            dis_ep: np.ndarray, coll_ep: np.ndarray, stale_slots: int,
+            dark_slots: int, dark_plane_slots: float,
+            fault_lost: float = 0.0, fault_refused: float = 0.0
+            ) -> AdaptiveRow:
+        """The case's row from what its serving loop summed per epoch
+        (delivered bits, plan disagreement, collision loss) and counted."""
+        c, n, bps = self.case, self.n, self.bits_per_slot
+        E, H = c.epoch_slots, c.wl.horizon
+        ep_len = np.minimum(E, H - E * np.arange(self.n_epochs))
+        ep_cap = ep_len * n * c.d_hat * bps
+        delivered = float(delivered_ep.sum())
+        result = SimResult(
+            fct_slots=fct, flow_size=c.wl.size,
+            utilization=delivered / (H * n * c.d_hat * bps),
+            delivered_bits=delivered,
+            offered_bits=float(c.wl.size[c.wl.arrival < H].sum()),
+            fault_lost_bits=fault_lost, fault_refused_bits=fault_refused)
+        return AdaptiveRow(
+            label=c.label, policy=c.policy, result=result,
+            epoch_utilization=delivered_ep / ep_cap,
+            epoch_estimate_tv=self.est_tv, recomputes=self.recomputes,
+            meta=dict(c.meta), stale_slots=stale_slots,
+            construction_s=self.construction_s, dark_slots=dark_slots,
+            epoch_disagreement=dis_ep / ep_len,
+            epoch_collision_loss=coll_ep / ep_cap,
+            collision_lost_bits=float(coll_ep.sum()),
+            schedule_groups_max=self.groups_max,
+            fault_lost_bits=fault_lost, fault_refused_bits=fault_refused,
+            dark_plane_slots=dark_plane_slots,
+            excised_nodes=int((self.excised_tx | self.excised_rx).sum()),
+            excised_planes=int((~self.plane_alive).sum()))
+
+
+def _served_support(served: np.ndarray, rows: np.ndarray, n: int,
+                    w: float) -> tuple[np.ndarray, np.ndarray]:
+    """A slot's ``(pair_id, capacity)`` support from its served claims:
+    ``served[r, i]`` grants input i the port ``rows[r, i]`` of claim row
+    r, ``w`` bits each."""
+    srr, sii = np.nonzero(served)
+    spid, inv = np.unique(sii * n + rows[srr, sii], return_inverse=True)
+    return spid, np.bincount(inv).astype(np.float64) * w
+
+
+def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
+                       san=None) -> AdaptiveRow:
+    """Serve one case slot by slot on the numpy engine, stepping its
+    :class:`_AdaptiveControl` at every slot: the exact f64 reference, and
+    the only path for faults, repair, ``fullest`` and activation jitter,
+    whose decisions read what was served."""
+    ctl = _AdaptiveControl(case, bits_per_slot, san=san)
+    wl, n = case.wl, case.wl.n
+    E, H = case.epoch_slots, wl.horizon
+    order, bucket, f_size = ctl.order, ctl.bucket, ctl.f_size
+    plane_dark_until = ctl.plane_dark_until
+
+    # flow state shared across epochs — a schedule hot-swap never resets it
+    pid = (wl.src * n + wl.dst).astype(np.int64)
+    fct = np.full(wl.num_flows, np.inf)
+    credit = _CreditState(n * n, pid, f_size, wl.arrival, fct)
+    voq = np.zeros(n * n)
+    # per-node VOQ byte counters, accumulated over the running epoch (A2)
+    counters = np.zeros((n, n))
+
+    delivered_ep = np.zeros(ctl.n_epochs)
+    dis_ep = np.zeros(ctl.n_epochs)  # summed per-slot plan disagreement
+    coll_ep = np.zeros(ctl.n_epochs)  # bits of capacity lost to collisions
     stale_slots = 0
     dark_slots = 0
-    groups_max = 1
     injected_cum = 0.0              # sanitizer's running bit ledger
 
     # --- degraded-service state (all inert on the historical fast path) --
@@ -2072,167 +2330,30 @@ def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
     tl = case.faults.compile(n, case.d_hat) if case.faults else None
     fault_lost = 0.0                # VOQ bits stranded by tor_fail
     fault_refused = 0.0             # arrivals refused at drained/dead ToRs
-    plane_dark_until = np.zeros(case.d_hat, dtype=np.int64)
     dark_plane_slots = 0.0
-    jit = int(case.activation_jitter_slots)
-    act_rng = np.random.default_rng([abs(int(case.seed)), 0xAC7])
-    # (old_fp, old_t0, per-node activation slots, end slot) while a
-    # jittered swap is mid-transition, else None
-    transition: tuple[_FabricPlan, int, np.ndarray, int] | None = None
-    # repair-loop detection state
-    tx_silent = np.zeros(n, dtype=np.int64)   # consecutive silent epochs
-    excised_tx = np.zeros(n, dtype=bool)
-    excised_rx = np.zeros(n, dtype=bool)
-    plane_alive = np.ones(case.d_hat, dtype=bool)  # repair's fabric view
-    rx_want = np.zeros(n)
-    rx_nack = np.zeros(n)
-    plane_want = np.zeros(case.d_hat)
-    plane_nack = np.zeros(case.d_hat)
-    # churn hysteresis: normalized estimate + repair state at last rebuild
-    last_est: np.ndarray | None = None
-    last_sig: tuple | None = None
-
-    def activate(new_fp: _FabricPlan, s: int) -> None:
-        """Install a newly built plan at slot ``s``: darken only the
-        planes whose matchings actually changed, and (under activation
-        jitter) open the mixed old/new transition window."""
-        nonlocal fp, sched_t0, transition, groups_max
-        if penalty:
-            om, nm = fp.plane_map, new_fp.plane_map
-            if (fp.eff is None or new_fp.eff is None
-                    or fp.eff.shape != new_fp.eff.shape
-                    or not np.array_equal(om, nm)):
-                plane_dark_until[nm] = s + penalty   # everything retargets
-            else:
-                ch = planes_changed(fp.eff, new_fp.eff, len(nm))
-                plane_dark_until[nm[ch]] = s + penalty
-        if jit:
-            act = s + act_rng.integers(0, jit + 1, size=n)
-            transition = (fp, sched_t0, act, s + jit + 1)
-        fp, sched_t0 = new_fp, s
-        groups_max = max(groups_max, new_fp.groups)
+    # repair's per-epoch observations: wanting circuits and their NACKs,
+    # per destination and per plane
+    rx_want, rx_nack = np.zeros(n), np.zeros(n)
+    plane_want, plane_nack = np.zeros(case.d_hat), np.zeros(case.d_hat)
+    repair_obs = (rx_want, rx_nack, plane_want, plane_nack)
 
     for slot in range(H):
-        if pending is not None and slot >= pending[0]:
-            swap_fp = pending[1]
-            pending = None
-            activate(swap_fp, slot)
-        if slot and slot % E == 0:
-            epoch = slot // E
+        if ctl.begin_slot(slot):
             if san is not None:
-                san.set_context(
-                    f"case={case.label} epoch={epoch} slot={slot}")
                 # per-epoch bit ledger: collision loss and dark windows are
                 # capacity-side, so queued bits close the ledger exactly;
                 # tor_fail strands bits, charged to the fault_lost term
                 san.check_conservation(
                     injected_cum, float(delivered_ep.sum()),
                     float(voq.sum()), fault_lost=fault_lost,
-                    label=f"adaptive:epoch{epoch - 1}:conservation")
-            repair_now = case.repair and case.policy == "adaptive"
-            if repair_now:
-                # dead senders: gather rows silent for repair_after_epochs
-                # consecutive epochs (the fleet EWMA would otherwise keep
-                # allocating circuits to a row that stopped refreshing)
-                silent = counters.sum(axis=1) <= 0.0
-                tx_silent[:] = np.where(silent, tx_silent + 1, 0)
-                excised_tx |= tx_silent >= case.repair_after_epochs
-                # dead receivers / planes: the data plane counts wanting
-                # circuits whose far side never carried (fault-masked) as
-                # NACKs; a near-total NACK ratio flags the target.  A dead
-                # plane NACKs ~all its claims, a dead ToR ~all claims
-                # toward it on every plane; a single dead port sits at
-                # ~1/d_hat on both counters and is left in place
-                # (degraded service, no excision).
-                excised_rx |= (rx_want > 10) & (rx_nack > 0.9 * rx_want)
-                plane_alive &= ~((plane_want > 10)
-                                 & (plane_nack > 0.9 * plane_want))
-                rx_want[:] = 0.0
-                rx_nack[:] = 0.0
-                plane_want[:] = 0.0
-                plane_nack[:] = 0.0
-            swap = None
-            if case.policy == "adaptive":
-                views = estimate_all_views(
-                    counters, fleet, case.k, q_unit,
-                    steps=case.gather_steps)
-                if san is not None:
-                    san.check_views(views)
-                if repair_now and (excised_tx.any() or excised_rx.any()):
-                    # excise failed senders/receivers from the estimate so
-                    # the rebuild allocates their capacity to healthy ports
-                    views = views.excise(excised_tx, excised_rx)
-                t = true_epoch[epoch - 1]
-                masks, owner = views.unique()
-                # estimate error: per-node TV distance vs the epoch truth,
-                # averaged over nodes (one term per unique view, weighted
-                # by its group size — a complete gather has one group and
-                # reduces to the historical single-estimate metric).  The
-                # per-view normalizations differ, so the metric is
-                # inherently O(G n^2); G == 1 on the consistent path, and
-                # under full disagreement (G == n) schedule construction
-                # already dominates this same order of work.
-                counts = np.bincount(owner, minlength=masks.shape[0])
-                t_sum = t.sum()
-                tn = t / t_sum if t_sum > 0 else None
-                # cheap emptiness predicate per group (exact for
-                # nonnegative rows); the actual normalizer below keeps the
-                # historical full-matrix summation order bit-for-bit
-                nonempty = (masks @ views.rows.sum(axis=1)) > 0
-                tvs, wts = [], []
-                for g in range(masks.shape[0]):
-                    if tn is not None and nonempty[g]:
-                        est_g = views.rows * masks[g][:, None]
-                        tvs.append(0.5 * np.abs(
-                            est_g / est_g.sum() - tn).sum())
-                        wts.append(counts[g])
-                if tvs:
-                    est_tv[epoch - 1] = float(np.average(tvs, weights=wts))
-                build = views.rows.sum() > 0
-                if build and case.swap_tv_threshold > 0.0:
-                    # churn hysteresis: skip the rebuild while the
-                    # estimate hasn't materially moved and the repair
-                    # state (excisions, surviving planes) is unchanged —
-                    # a converged stationary estimate stops paying the
-                    # reconfiguration dark window
-                    cur = views.rows / views.rows.sum()
-                    sig = (plane_alive.tobytes(), excised_tx.tobytes(),
-                           excised_rx.tobytes())
-                    if (last_est is not None and sig == last_sig
-                            and 0.5 * np.abs(cur - last_est).sum()
-                                < case.swap_tv_threshold):
-                        build = False
-                    else:
-                        last_est, last_sig = cur, sig
-                if build:
-                    if repair_now and not plane_alive.all():
-                        dl = int(plane_alive.sum())
-                        if dl > 0:  # rebuild over the surviving planes
-                            swap = vsched_per_node(
-                                views, case.seed + epoch, (masks, owner),
-                                d_hat=dl,
-                                plane_map=np.nonzero(plane_alive)[0])
-                    else:
-                        swap = vsched_per_node(views, case.seed + epoch,
-                                               (masks, owner))
-            elif case.policy == "oracle":
-                if oracle_m[epoch].sum() > 0:
-                    swap = consistent_plan(
-                        vsched(oracle_m[epoch], case.seed + epoch))
-            if swap is not None:
-                recomputes += 1
-                charge = (int(np.ceil(last_construction / case.slot_seconds))
-                          if measured else int(cs))
-                if charge == 0:
-                    pending = None   # a zero-cost swap supersedes any pending
-                    activate(swap, slot)
-                else:
-                    # the stale schedule keeps serving until construction
-                    # finishes; a recompute next epoch supersedes this one
-                    pending = (slot + charge, swap)
+                    label=f"adaptive:epoch{slot // E - 1}:conservation")
+            ctl.epoch_boundary(slot, counters, repair_obs)
             counters[:] = 0.0
-        if pending is not None:
+            for obs in repair_obs:
+                obs[:] = 0.0
+        if ctl.pending is not None:
             stale_slots += 1
+        fp, sched_t0 = ctl.fp, ctl.sched_t0
 
         if tl is not None:
             for f in tl.advance(slot):  # abrupt death strands the VOQs
@@ -2260,11 +2381,9 @@ def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
             continue                # they contribute zero disagreement and
                                     # zero collision loss — one time base
                                     # for both per-epoch metrics)
-        if transition is not None and slot >= transition[3]:
-            transition = None
 
         faulty = tl is not None and not tl.clean
-        if (not faulty and transition is None and not dark.any()
+        if (not faulty and ctl.transition is None and not dark.any()
                 and fp.plans is not None):
             # historical fast path, bit-identical to the pre-fault engine
             dis_ep[slot // E] += fp.disagreement
@@ -2281,7 +2400,7 @@ def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
         # --- degraded-service path: rebuild this slot from raw claims ---
         dark_plane_slots += float(dark.sum())
         dis_ep[slot // E] += fp.disagreement
-        if transition is None:
+        if ctl.transition is None:
             dl = len(fp.plane_map)
             lo = ((slot - sched_t0) % fp.n_slots) * dl
             hi = min(lo + dl, fp.eff.shape[0])
@@ -2303,7 +2422,7 @@ def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
             # mixed old/new activation: each node serves its own
             # generation; contention between the generations on the same
             # physical plane is re-arbitrated per slot
-            ofp, ot0, act, _ = transition
+            ofp, ot0, act, _ = ctl.transition
             blocks = []
             for p, t0 in ((ofp, ot0), (fp, sched_t0)):
                 dlp = len(p.plane_map)
@@ -2342,11 +2461,8 @@ def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
                 np.add.at(rx_nack, rows[wanting & ~rxok], 1.0)
             served &= txok & rxok
 
-        srr, sii = np.nonzero(served)
-        if srr.size:
-            spid, inv = np.unique(sii * n + rows[srr, sii],
-                                  return_inverse=True)
-            scap = np.bincount(inv).astype(np.float64) * fp.w
+        spid, scap = _served_support(served, rows, n, fp.w)
+        if spid.size:
             q = voq[spid]
             tx = np.minimum(q, scap)
             voq[spid] = q - tx
@@ -2364,33 +2480,9 @@ def _run_adaptive_case(case: AdaptiveCase, bits_per_slot: float,
         san.check_credit_closure(injected_cum, delivered_all, rem,
                                  completed, label="adaptive:credit")
         san.set_context(None)
-    ep_len = np.minimum(E, H - E * np.arange(n_epochs))
-    ep_cap = ep_len * n * case.d_hat * bits_per_slot
-    ideal = H * n * case.d_hat * bits_per_slot
-    result = SimResult(
-        fct_slots=fct,
-        flow_size=wl.size,
-        utilization=float(delivered_ep.sum()) / ideal,
-        delivered_bits=float(delivered_ep.sum()),
-        offered_bits=float(wl.size[valid].sum()),
-        fault_lost_bits=fault_lost,
-        fault_refused_bits=fault_refused,
-    )
-    return AdaptiveRow(
-        label=case.label, policy=case.policy, result=result,
-        epoch_utilization=delivered_ep / ep_cap, epoch_estimate_tv=est_tv,
-        recomputes=recomputes, meta=dict(case.meta),
-        stale_slots=stale_slots, construction_s=construction_s,
-        dark_slots=dark_slots,
-        epoch_disagreement=dis_ep / ep_len,
-        epoch_collision_loss=coll_ep / ep_cap,
-        collision_lost_bits=float(coll_ep.sum()),
-        schedule_groups_max=groups_max,
-        fault_lost_bits=fault_lost,
-        fault_refused_bits=fault_refused,
-        dark_plane_slots=dark_plane_slots,
-        excised_nodes=int((excised_tx | excised_rx).sum()),
-        excised_planes=int((~plane_alive).sum()))
+    return ctl.row(fct, delivered_ep, dis_ep, coll_ep, stale_slots,
+                   dark_slots, dark_plane_slots, fault_lost=fault_lost,
+                   fault_refused=fault_refused)
 
 
 def run_adaptive(
@@ -2414,18 +2506,16 @@ def run_adaptive(
     report per-epoch disagreement and collision-loss alongside
     utilization.
 
-    ``backend="jax"`` runs the whole grid through one jitted device scan
-    per node count: the control plane (estimation → per-node schedules →
-    collision-resolved plans → activation/dark windows) is replayed
-    host-side exactly — the counters that drive it accumulate *arrivals*
-    only, so the full epoch trajectory is computable before any serving —
-    and the resulting per-slot circuit plans for every case batch through
-    the shared single-hop kernel, with per-flow FCTs recovered by the
-    host credit replay.  Cases the device path cannot express raise up
-    front — ``NotImplementedError`` for fault injection (a numpy-only
-    feature, ROADMAP follow-up), ``ValueError`` for ``repair=True``,
-    ``collision="fullest"``, and activation jitter; use the numpy backend
-    for those.
+    Both backends step one control object per case (``_AdaptiveControl``:
+    estimation → per-node schedules → collision-resolved plans →
+    activation and dark windows).  ``backend="jax"`` feeds it counters of
+    *arrivals* only, so each case's whole per-slot plan is compiled before
+    serving; the grid then runs in one jitted device scan per node count
+    through the shared single-hop kernel, with per-flow FCTs from the host
+    credit replay.  Cases the device path cannot express raise up front —
+    ``NotImplementedError`` for fault injection, ``ValueError`` for
+    ``repair=True``, ``collision="fullest"`` and activation jitter; use the
+    numpy backend for those.
 
     ``sanitize``: run the :mod:`repro.analysis.sanitize` contract checks —
     per-epoch bit conservation, fabric-plan validity, disagreement closure
@@ -2494,8 +2584,8 @@ def _check_adaptive_jax_supported(case: "AdaptiveCase", i: int) -> None:
 # signature.  _JAX_TRACES counts actual retraces (the kernel's Python body
 # only runs while jax traces it); a regression test pins it.
 _JAX_FNS: dict[str, "callable"] = {}
-_JAX_TRACES = {"agg": 0, "twohop_dense": 0, "twohop_sparse": 0,
-               "singlehop": 0, "twohop_fct": 0}
+_JAX_TRACES = {"twohop_dense": 0, "twohop_sparse": 0, "singlehop": 0,
+               "twohop_fct": 0}
 # Per-kernel call counts and the padded shape buckets seen, for
 # compile_cache_stats(): hits = calls - traces (a call whose padded
 # signature was already compiled never re-enters the traced Python body).
@@ -2561,7 +2651,6 @@ def compile_cache_stats() -> dict:
 # the contract between the compile cache and the IR analyzer
 # (repro.analysis.ir traces kernels at these padded signatures).
 KERNEL_BUCKET_DIMS = {
-    "agg": ("B", "n", "H_pad"),
     "twohop_dense": ("B", "n", "H_pad", "K"),
     "twohop_fct": ("B", "n", "H_pad", "K"),
     "twohop_sparse": ("B", "n", "H_pad", "K", "J", "P"),
@@ -2607,8 +2696,6 @@ def kernel_abstract_inputs(
     asz = S((H_pad, K), f32)
     live = S((H_pad, B), f32)
     direct = S((B, 1, 1), f32)
-    if kernel == "agg":
-        return (caps_flat, cap_idx, S((H_pad, B, n, n), f32), live)
     if kernel in ("twohop_dense", "twohop_fct"):
         return (caps_flat, cap_idx, apos, asz, live, direct)
     if kernel == "twohop_sparse":
@@ -2660,23 +2747,6 @@ def _jax_fns() -> dict:
     # the sanitizer can close the bit ledger (injected = delivered +
     # queued) without re-running anything; the carry is aggregate VOQ /
     # relay state the scan holds anyway.
-
-    def agg(caps_flat, cap_idx, arr, live):
-        _JAX_TRACES["agg"] += 1
-        B, n = arr.shape[1], arr.shape[2]
-
-        def step(voq, inp):
-            idx, a, lv = inp
-            voq = voq + a
-            cap = caps_flat[idx] * lv[:, None, None]
-            tx = jnp.minimum(voq, cap)
-            return voq - tx, tx.sum(axis=(1, 2))
-
-        voq_f, delivered = jax.lax.scan(
-            step,
-            jnp.zeros((B, n, n), jnp.float32),  # lint: allow-dense
-            (cap_idx, arr, live))
-        return delivered, voq_f
 
     # Both two-hop kernels carry relay state as per-(at, dst) bucket
     # TOTALS only (the NumPy engine's maintained RS array, without the
@@ -2889,7 +2959,6 @@ def _jax_fns() -> dict:
         return out, carry
 
     _JAX_FNS.update(
-        agg=jax.jit(agg),
         twohop_dense=jax.jit(twohop_dense),
         twohop_sparse=jax.jit(twohop_sparse),
         singlehop=jax.jit(singlehop),
@@ -3402,291 +3471,48 @@ def _sparse_plan_lut(supports, n: int, B: int, H: int,
     return [plan_idx, p_row, p_v, p_b, p_valid]
 
 
-def simulate_aggregate_jax(
-    sched: Schedule, arrivals: np.ndarray, bits_per_slot: float
-):
-    """Single-hop aggregate dynamics on the accelerator.
-    Returns (delivered_per_slot, final_voq).
-
-    ``arrivals``: (horizon, n, n) bits arriving per slot.
-
-    Runs as a B = 1 batch through the module's cached ``agg`` scan kernel
-    (horizon padded to the ``_PAD_H`` bucket with dead slots — exact
-    no-ops), so repeated calls at the same padded shape never retrace;
-    the PR 4 compile-cache discipline applies here too.
-    """
-    fns = _jax_fns()
-    arrivals = np.asarray(arrivals, dtype=np.float32)
-    horizon, n = arrivals.shape[0], arrivals.shape[1]
-    caps_flat = sched.capacity_per_slot(bits_per_slot).astype(np.float32)
-    ns = caps_flat.shape[0]
-    H_pad = _pad_to(horizon, _PAD_H)
-    cap_idx = np.zeros((H_pad, 1), dtype=np.int32)
-    cap_idx[:horizon, 0] = np.arange(horizon) % ns
-    live = np.zeros((H_pad, 1), dtype=np.float32)
-    live[:horizon, 0] = 1.0
-    arr = np.zeros((H_pad, 1, n, n), dtype=np.float32)  # lint: allow-dense
-    arr[:horizon, 0] = arrivals
-    _record_call("agg", (1, n, H_pad))
-    delivered, voq_f = fns["agg"](caps_flat, cap_idx, arr, live)
-    return np.asarray(delivered)[:horizon, 0], np.asarray(voq_f)[0]
-
-
 # ---------------------------------------------------------------------------
 # JAX adaptive backend: host-compiled control plane + one device scan
 # ---------------------------------------------------------------------------
 
 def _compile_adaptive_plan(case: AdaptiveCase, bits_per_slot: float,
-                           san=None, sched_cache: dict | None = None):
-    """Host-side replay of the adaptive control loop WITHOUT serving.
+                           san=None, cache: dict | None = None):
+    """The adaptive control trajectory of one case, compiled WITHOUT
+    serving.
 
-    The epoch counters that drive the control plane accumulate *arrival*
-    bits only — never served bits — so for every jax-supported case the
-    whole control trajectory (fleet EWMA → quantized ring gather →
-    per-node schedules → collision-resolved fabric plans → construction
-    charging → activation dark windows → churn hysteresis) is computable
-    before any serving happens.  This mirrors :func:`_run_adaptive_case`
-    decision-for-decision (bit-identical counters: one ``np.add.at`` over
-    the epoch's stable-ordered arrival slice reproduces the per-slot
-    accumulation element-for-element) and emits, per slot, an index into a
-    registry of ``(pair_id, capacity)`` circuit plans the device scan then
-    serves.  Registry id 0 is the empty plan (fully-dark slots).
-
-    ``sched_cache`` (shared across a batch) memoizes schedule
-    *construction* on the exact estimator inputs — the expensive
-    ``vermilion_schedule`` / ``per_node_schedules`` calls — so a grid that
-    varies only the collision policy pays construction once; the (cheap,
-    collision-specific) ``_fabric_plan`` merge always runs.  Disabled for
-    ``construction_slots="measured"``, where the charge is the actual
-    wall-clock of a fresh construction.
+    It steps the same :class:`_AdaptiveControl` as
+    :func:`_run_adaptive_case`.  The epoch counters that drive it
+    accumulate *arrival* bits only — never served bits — so for every
+    jax-supported case the whole trajectory (fleet EWMA → quantized ring
+    gather → per-node schedules → collision-resolved fabric plans →
+    construction charging → activation dark windows → churn hysteresis)
+    is known before any serving happens.  It emits, per slot, an index
+    into a registry of ``(pair_id, capacity)`` circuit plans the device
+    scan then serves.  Registry id 0 is the empty plan (fully-dark slots).
+    ``cache`` is the batch's memo (see :class:`_AdaptiveControl`).
     """
-    wl, n = case.wl, case.wl.n
-    E, H = case.epoch_slots, wl.horizon
-    n_epochs = -(-H // E)
-    cs = case.construction_slots
-    measured = cs == "measured"
-    if measured:
-        sched_cache = None
-    penalty = int(case.reconfig_penalty_slots)
-    if san is not None:
-        san.set_context(f"case={case.label}")
-        san.check_workload(wl)
-    san_w = bits_per_slot * (1.0 - case.recfg_frac)
+    ctl = _AdaptiveControl(case, bits_per_slot, san=san, cache=cache)
+    n, E, H = case.wl.n, case.epoch_slots, case.wl.horizon
+    plane_dark_until = ctl.plane_dark_until
 
-    f_size = wl.size.astype(np.float64)
-    valid = wl.arrival < H
-    order = np.argsort(wl.arrival, kind="stable")
-    order = order[valid[order]]
-    bucket = np.searchsorted(wl.arrival[order], np.arange(H + 1))
-
-    true_epoch = np.zeros((n_epochs, n, n))  # lint: allow-dense
-    np.add.at(true_epoch,
-              (wl.arrival[order] // E, wl.src[order], wl.dst[order]),
-              f_size[order])
-    oracle_m = case.oracle_demand
-    if oracle_m is not None and oracle_m.shape != (n_epochs, n, n):
-        raise ValueError(
-            f"oracle_demand shape {oracle_m.shape} != {(n_epochs, n, n)}")
-    if oracle_m is None:
-        oracle_m = true_epoch / E
-
-    fleet = TrafficEstimator.fleet(n, alpha=case.alpha)
-    q_unit = _quantizer_unit(E, case.k, case.d_hat, bits_per_slot)
-
-    construction_s = 0.0
-    last_construction = 0.0
-    cache_key_base = (case.k, case.d_hat, case.recfg_frac, case.normalize,
-                      case.method)
-
-    def consistent_plan(sched: Schedule) -> _FabricPlan:
-        fp = _fabric_plan([sched], np.zeros(n, dtype=np.int64),
-                          bits_per_slot, case.collision)
-        if san is not None:
-            san.check_schedule(sched)
-            san.check_fabric_plan(fp, n, sched.d_hat, san_w)
-        return fp
-
-    def vsched(m: np.ndarray, seed: int) -> Schedule:
-        nonlocal construction_s, last_construction
-        key = None
-        if sched_cache is not None:
-            key = ("v", m.tobytes(), seed) + cache_key_base
-            hit = sched_cache.get(key)
-            if hit is not None:
-                s, dt = hit
-                last_construction = dt
-                construction_s += dt
-                return s
-        t0 = time.perf_counter()
-        s = vermilion_schedule(
-            m, k=case.k, d_hat=case.d_hat, recfg_frac=case.recfg_frac,
-            seed=seed, normalize=case.normalize, method=case.method)
-        last_construction = time.perf_counter() - t0
-        construction_s += last_construction
-        if key is not None:
-            sched_cache[key] = (s, last_construction)
-        return s
-
-    def vsched_per_node(views, seed: int, unique) -> _FabricPlan:
-        nonlocal construction_s, last_construction
-        masks, owner = unique
-        key = None
-        if sched_cache is not None:
-            key = ("pn", views.rows.tobytes(), masks.tobytes(),
-                   owner.tobytes(), seed) + cache_key_base
-            hit = sched_cache.get(key)
-            if hit is not None:
-                scheds, sowner, dt = hit
-            else:
-                hit = None
-        if sched_cache is None or hit is None:
-            t0 = time.perf_counter()
-            scheds, sowner = per_node_schedules(
-                views, k=case.k, d_hat=case.d_hat,
-                recfg_frac=case.recfg_frac, seed=seed,
-                normalize=case.normalize, method=case.method, unique=unique)
-            dt = time.perf_counter() - t0
-            if key is not None:
-                sched_cache[key] = (scheds, sowner, dt)
-        construction_s += dt
-        # the fabric waits for one local construction (see
-        # _run_adaptive_case.vsched_per_node)
-        last_construction = dt / len(scheds)
-        fp = _fabric_plan(scheds, sowner, bits_per_slot, case.collision)
-        if san is not None:
-            for s in scheds:
-                san.check_schedule(s)
-            san.check_fabric_plan(fp, n, case.d_hat, san_w)
-        return fp
-
-    if case.policy in ("oracle", "stale"):
-        fp = consistent_plan(vsched(oracle_m[0], case.seed))
-    else:
-        fp = consistent_plan(oblivious_schedule(n, d_hat=case.d_hat,
-                                                recfg_frac=case.recfg_frac))
-    sched_t0 = 0
-    pending: tuple[int, _FabricPlan] | None = None
-
-    est_tv = np.full(n_epochs, np.nan)
     dis_slot = np.zeros(H)
     coll_slot = np.zeros(H)
     plan_ids = np.zeros(H, dtype=np.int32)
     registry: list[tuple[np.ndarray, np.ndarray]] = [
         (np.empty(0, dtype=np.int64), np.empty(0, dtype=np.float64))]
-    memo: dict[tuple, int] = {}
-    keep_alive: list = [fp]        # plans are memo-keyed by id(); pin them
-    recomputes = 0
+    memo: dict = {}                 # registry ids of the installed plan
+    memo_fp = None
     stale_slots = 0
     dark_slots = 0
     dark_plane_slots = 0.0
-    groups_max = 1
-    plane_dark_until = np.zeros(case.d_hat, dtype=np.int64)
-    counters = np.zeros((n, n))
-    last_est: np.ndarray | None = None
-    last_sig: tuple | None = None
-
-    def activate(new_fp: _FabricPlan, s: int) -> None:
-        nonlocal fp, sched_t0, groups_max
-        if penalty:
-            om, nm = fp.plane_map, new_fp.plane_map
-            if (fp.eff is None or new_fp.eff is None
-                    or fp.eff.shape != new_fp.eff.shape
-                    or not np.array_equal(om, nm)):
-                plane_dark_until[nm] = s + penalty
-            else:
-                ch = planes_changed(fp.eff, new_fp.eff, len(nm))
-                plane_dark_until[nm[ch]] = s + penalty
-        fp, sched_t0 = new_fp, s
-        keep_alive.append(new_fp)
-        groups_max = max(groups_max, new_fp.groups)
 
     slot = 0
     while slot < H:
-        if pending is not None and slot >= pending[0]:
-            swap_fp = pending[1]
-            pending = None
-            activate(swap_fp, slot)
-        if slot and slot % E == 0:
-            epoch = slot // E
-            if san is not None:
-                san.set_context(
-                    f"case={case.label} epoch={epoch} slot={slot}")
-            # bit-identical counter replica: the numpy loop adds each
-            # slot's stable-ordered arrival slice via one np.add.at; one
-            # np.add.at over the epoch's concatenated slice performs the
-            # identical element-ordered float accumulation
-            swap = None
-            if case.policy == "adaptive":
-                # the estimation round and its TV-accuracy metric are
-                # collision-independent, so a grid varying only the
-                # data-plane resolution computes each epoch's views once
-                # (keyed per epoch: the fleet EWMA is stateful, so a case
-                # either hits every epoch of a cached trajectory or
-                # replays the whole chain itself)
-                ctl_key = None
-                ctl = None
-                if sched_cache is not None and san is None:
-                    ctl_key = ("ctl", id(wl), epoch, case.gather_steps,
-                               case.alpha, E, case.seed) + cache_key_base
-                    ctl = sched_cache.get(ctl_key)
-                if ctl is None:
-                    counters[:] = 0.0
-                    seg = order[bucket[(epoch - 1) * E]:bucket[epoch * E]]
-                    np.add.at(counters, (wl.src[seg], wl.dst[seg]),
-                              f_size[seg])
-                    views = estimate_all_views(
-                        counters, fleet, case.k, q_unit,
-                        steps=case.gather_steps)
-                    if san is not None:
-                        san.check_views(views)
-                    t = true_epoch[epoch - 1]
-                    masks, owner = views.unique()
-                    counts = np.bincount(owner, minlength=masks.shape[0])
-                    t_sum = t.sum()
-                    tn = t / t_sum if t_sum > 0 else None
-                    nonempty = (masks @ views.rows.sum(axis=1)) > 0
-                    tvs, wts = [], []
-                    for g in range(masks.shape[0]):
-                        if tn is not None and nonempty[g]:
-                            est_g = views.rows * masks[g][:, None]
-                            tvs.append(0.5 * np.abs(
-                                est_g / est_g.sum() - tn).sum())
-                            wts.append(counts[g])
-                    tv_val = (float(np.average(tvs, weights=wts))
-                              if tvs else None)
-                    if ctl_key is not None:
-                        sched_cache[ctl_key] = (views, masks, owner, tv_val)
-                else:
-                    views, masks, owner, tv_val = ctl
-                if tv_val is not None:
-                    est_tv[epoch - 1] = tv_val
-                build = views.rows.sum() > 0
-                if build and case.swap_tv_threshold > 0.0:
-                    cur = views.rows / views.rows.sum()
-                    sig = (b"", b"", b"")   # no repair state on this path
-                    if (last_est is not None and sig == last_sig
-                            and 0.5 * np.abs(cur - last_est).sum()
-                                < case.swap_tv_threshold):
-                        build = False
-                    else:
-                        last_est, last_sig = cur, sig
-                if build:
-                    swap = vsched_per_node(views, case.seed + epoch,
-                                           (masks, owner))
-            elif case.policy == "oracle":
-                if oracle_m[epoch].sum() > 0:
-                    swap = consistent_plan(
-                        vsched(oracle_m[epoch], case.seed + epoch))
-            if swap is not None:
-                recomputes += 1
-                charge = (int(np.ceil(last_construction
-                                      / case.slot_seconds))
-                          if measured else int(cs))
-                if charge == 0:
-                    pending = None
-                    activate(swap, slot)
-                else:
-                    pending = (slot + charge, swap)
+        if ctl.begin_slot(slot):
+            ctl.epoch_boundary(slot)
+        fp, sched_t0, pending = ctl.fp, ctl.sched_t0, ctl.pending
+        if fp is not memo_fp:
+            memo, memo_fp = {}, fp
         # per-slot state (fabric, pending status, per-plane darkness) is
         # constant until the next control event, so the whole run of slots
         # up to it is classified and filled in one vectorized pass — the
@@ -3715,7 +3541,7 @@ def _compile_adaptive_plan(case: AdaptiveCase, bits_per_slot: float,
             dis_slot[seg] = fp.disagreement
             coll_slot[seg] = fp.lost[ps_arr]
             for p in np.unique(ps_arr):
-                key = (id(fp), int(p))
+                key = int(p)
                 idx = memo.get(key)
                 if idx is None:
                     idx = memo[key] = len(registry)
@@ -3739,20 +3565,12 @@ def _compile_adaptive_plan(case: AdaptiveCase, bits_per_slot: float,
             nonself = fp.nonself[lo:hi]
             win = fp.win[lo:hi]
             coll_u[p] = float((nonself & live & ~win).sum()) * fp.w
-            key = (id(fp), lo, live.tobytes())
+            key = (lo, live.tobytes())
             idx = memo.get(key)
             if idx is None:
-                served = win & nonself & live
-                srr, sii = np.nonzero(served)
-                if srr.size:
-                    spid, inv = np.unique(sii * n + rows_e[srr, sii],
-                                          return_inverse=True)
-                    scap = np.bincount(inv).astype(np.float64) * fp.w
-                else:
-                    spid = np.empty(0, dtype=np.int64)
-                    scap = np.empty(0, dtype=np.float64)
                 idx = memo[key] = len(registry)
-                registry.append((spid, scap))
+                registry.append(_served_support(win & nonself & live,
+                                                rows_e, n, fp.w))
             ids_u[p] = idx
         coll_slot[seg] = coll_u[ps_arr]
         plan_ids[seg] = ids_u[ps_arr]
@@ -3762,11 +3580,9 @@ def _compile_adaptive_plan(case: AdaptiveCase, bits_per_slot: float,
         san.set_context(None)
     return {
         "registry": registry, "plan_ids": plan_ids,
-        "dis_slot": dis_slot, "coll_slot": coll_slot, "est_tv": est_tv,
-        "recomputes": recomputes, "stale_slots": stale_slots,
-        "dark_slots": dark_slots, "dark_plane_slots": dark_plane_slots,
-        "groups_max": groups_max, "construction_s": construction_s,
-        "n_epochs": n_epochs, "keep_alive": keep_alive,
+        "dis_slot": dis_slot, "coll_slot": coll_slot, "ctl": ctl,
+        "stale_slots": stale_slots, "dark_slots": dark_slots,
+        "dark_plane_slots": dark_plane_slots,
     }
 
 
@@ -3786,9 +3602,9 @@ def _run_adaptive_batch_jax(
     H = int(horizons.max())
     H_pad = _pad_to(H, _PAD_H)
     with span("fabric.batch", kernel="singlehop", B=B, n=n, H_pad=H_pad):
-        sched_cache: dict = {}
+        cache: dict = {}
         compiled = [_compile_adaptive_plan(c, bits_per_slot, san=san,
-                                           sched_cache=sched_cache)
+                                           cache=cache)
                     for c in cases]
 
         # cases whose compiled data plane is byte-identical (same workload
@@ -3857,9 +3673,8 @@ def _run_adaptive_batch_jax(
                 voq64, = _fetch((voq_f, np.float64))
             rows = []
             for b, (case, cp) in enumerate(zip(cases, compiled)):
-                wl, E = case.wl, case.epoch_slots
-                h_b = int(horizons[b])
-                n_epochs = cp["n_epochs"]
+                h_b, E = int(horizons[b]), case.epoch_slots
+                n_epochs = cp["ctl"].n_epochs
                 u = uidx[rep_of[b]]
                 cols = slice(col_offs[u], col_offs[u + 1])
                 # strictly sequential per-epoch accumulation (np.add.at,
@@ -3873,36 +3688,17 @@ def _run_adaptive_batch_jax(
                 np.add.at(dis_ep, ep_idx, cp["dis_slot"])
                 coll_ep = np.zeros(n_epochs)
                 np.add.at(coll_ep, ep_idx, cp["coll_slot"])
-                ep_len = np.minimum(E, h_b - E * np.arange(n_epochs))
-                ep_cap = ep_len * n * case.d_hat * bits_per_slot
-                ideal = h_b * n * case.d_hat * bits_per_slot
-                delivered = float(delivered_ep.sum())
-                offered = float(wl.size[wl.arrival < h_b].sum())
+                row = cp["ctl"].row(
+                    fct[f_off[u]:f_off[u + 1]], delivered_ep, dis_ep,
+                    coll_ep, cp["stale_slots"], cp["dark_slots"],
+                    cp["dark_plane_slots"])
                 if san is not None:
                     queued = float(voq64[u * n * n:(u + 1) * n * n].sum())
                     san.check_conservation(
-                        offered, delivered, queued,
-                        label=f"jax:adaptive{b}:conservation", float32=True)
-                result = SimResult(
-                    fct_slots=fct[f_off[u]:f_off[u + 1]],
-                    flow_size=wl.size,
-                    utilization=delivered / ideal,
-                    delivered_bits=delivered,
-                    offered_bits=offered,
-                )
-                rows.append(AdaptiveRow(
-                    label=case.label, policy=case.policy, result=result,
-                    epoch_utilization=delivered_ep / ep_cap,
-                    epoch_estimate_tv=cp["est_tv"],
-                    recomputes=cp["recomputes"], meta=dict(case.meta),
-                    stale_slots=cp["stale_slots"],
-                    construction_s=cp["construction_s"],
-                    dark_slots=cp["dark_slots"],
-                    epoch_disagreement=dis_ep / ep_len,
-                    epoch_collision_loss=coll_ep / ep_cap,
-                    collision_lost_bits=float(coll_ep.sum()),
-                    schedule_groups_max=cp["groups_max"],
-                    dark_plane_slots=cp["dark_plane_slots"]))
+                        row.result.offered_bits, row.result.delivered_bits,
+                        queued, label=f"jax:adaptive{b}:conservation",
+                        float32=True)
+                rows.append(row)
             if san is not None:
                 rem, completed = credit.remaining_active()
                 san.check_credit_closure(

@@ -48,7 +48,7 @@ def assert_no_retrace():
             with assert_no_retrace():
                 hot_calls()           # counters must not move
 
-    Pass ``kernels=("agg",)`` to pin only a subset of the counters."""
+    Pass ``kernels=("singlehop",)`` to pin only a subset of the counters."""
     pytest.importorskip("jax")
     from repro.core.simulator import _JAX_TRACES
 

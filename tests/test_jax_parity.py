@@ -199,6 +199,25 @@ def test_adaptive_jax_batched_grid_matches_per_case():
         _assert_adaptive_parity(a, b)
 
 
+@pytest.mark.parametrize("gather_steps", [None, 4])
+def test_adaptive_jax_collision_grid_shares_control(gather_steps):
+    """One workload object under three collision policies in one call:
+    the batch shares construction and estimation across the cases and
+    serves byte-identical plans once, and every row still matches its own
+    numpy run.  The sanitizer is off, since it turns that sharing off."""
+    wl = _wl(51)
+    cases = [AdaptiveCase(wl=wl, d_hat=3, epoch_slots=150,
+                          gather_steps=gather_steps, collision=col,
+                          label=col)
+             for col in ("drop", "lowest", "receiver")]
+    rows_jx = run_adaptive(cases, bits_per_slot=BPS, backend="jax",
+                           sanitize=False)
+    for case, b in zip(cases, rows_jx):
+        a = run_adaptive([case], bits_per_slot=BPS, backend="numpy",
+                         sanitize=False)[0]
+        _assert_adaptive_parity(a, b)
+
+
 # ---------------------------------------------------------------------------
 # Backend validation: clear errors at entry, not deep in dispatch
 # ---------------------------------------------------------------------------
@@ -270,7 +289,7 @@ def test_compile_cache_stats_structure():
                            recfg_frac=RECFG)
     run_sweep([SweepCase(s, wl, "single_hop", "v")], BPS, backend="jax")
     stats = compile_cache_stats()
-    for kernel in ("agg", "twohop_dense", "twohop_sparse", "singlehop",
+    for kernel in ("twohop_dense", "twohop_sparse", "singlehop",
                    "twohop_fct"):
         assert kernel in stats
         entry = stats[kernel]

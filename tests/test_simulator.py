@@ -9,7 +9,6 @@ from repro.core.simulator import (
     Workload,
     run_sweep,
     simulate,
-    simulate_aggregate_jax,
     simulate_reference,
     websearch_workload,
 )
@@ -96,9 +95,10 @@ def test_jax_parity():
     pytest.importorskip("jax")
     wl = websearch_workload(6, 0.3, 300, BPS, d_hat=2, seed=2)
     s = vermilion_schedule(wl.demand_matrix(), k=3, d_hat=2, recfg_frac=RECFG)
-    r_np = simulate(s, wl, BPS)
-    d_jax, voq = simulate_aggregate_jax(s, wl.arrival_matrix(), BPS)
-    assert np.isclose(r_np.delivered_bits, float(d_jax.sum()), rtol=1e-5)
+    cases = [SweepCase(s, wl, "single_hop", "v")]
+    r_np = run_sweep(cases, BPS)[0].result
+    r_jax = run_sweep(cases, BPS, backend="jax")[0].result
+    assert np.isclose(r_np.delivered_bits, r_jax.delivered_bits, rtol=1e-5)
 
 
 def test_percentiles_api():
@@ -378,20 +378,6 @@ def test_jax_backend_no_retrace(assert_no_retrace):
     with assert_no_retrace():
         for _ in range(3):
             run_sweep(cases, BPS, backend="jax")
-
-
-def test_jax_aggregate_entrypoint_no_retrace(assert_no_retrace):
-    """``simulate_aggregate_jax`` rides the same compile cache as the
-    batched sweep (it used to build a fresh un-jitted scan per call)."""
-    pytest.importorskip("jax")
-    wl = websearch_workload(7, 0.4, 150, BPS, d_hat=2, seed=4)
-    s = vermilion_schedule(wl.demand_matrix(), k=3, d_hat=2,
-                           recfg_frac=RECFG)
-    arr = wl.arrival_matrix()
-    simulate_aggregate_jax(s, arr, BPS)           # compile (or cache hit)
-    with assert_no_retrace(kernels=("agg",)):
-        for _ in range(3):
-            simulate_aggregate_jax(s, arr, BPS)
 
 
 def test_jax_twohop_kernels_no_retrace(assert_no_retrace):
