@@ -65,9 +65,9 @@ through one slot loop with a leading batch axis:
    kernels, which carry relay state as per-(at, dst) bucket *totals* (the
    source-attribution axis exists only to credit flows, so it drops out
    of the aggregate dynamics exactly) and pick between a dense einsum
-   formulation (small n) and padded circuit-support gathers +
-   ``segment_sum`` over the same :class:`_SupportPlans` LUT the NumPy
-   engine uses (large n); their ``fct_slots`` stay all-inf.  Kernels jit
+   formulation (small n) and a fixed-degree circuit-support layout (D
+   slots per row, keyed by the same period residues as the NumPy engine's
+   :class:`_SupportPlans`; large n); their ``fct_slots`` stay all-inf.  Kernels jit
    once per padded shape bucket through a module-level compile cache —
    repeated same-shape sweeps never retrace
    (:func:`compile_cache_stats` introspects traces / hits / buckets).
@@ -863,10 +863,9 @@ class _SupportPlans:
     for a slot depends only on ``slot % ns_b`` per case (the residue tuple
     :meth:`key`), so plans are memoized on that tuple.
 
-    One builder serves both backends: the NumPy relay loop consumes the
-    memoized merged dicts (:meth:`plan`), the JAX backend densifies the
-    same merged plans into its padded ``(plan, J_pad)`` LUT, deduplicated
-    by :meth:`key` and scanned by per-slot plan index.
+    The NumPy relay loop consumes the memoized merged dicts
+    (:meth:`plan`); the JAX ``twohop_sparse`` kernel's tables
+    (:func:`_sparse_plan_lut`) are keyed by the same residue tuple.
     """
 
     _CAT = ("b", "row", "v", "bv", "row_l", "bv_l", "at")
@@ -2653,14 +2652,14 @@ def compile_cache_stats() -> dict:
 KERNEL_BUCKET_DIMS = {
     "twohop_dense": ("B", "n", "H_pad", "K"),
     "twohop_fct": ("B", "n", "H_pad", "K"),
-    "twohop_sparse": ("B", "n", "H_pad", "K", "J", "P"),
+    "twohop_sparse": ("B", "n", "H_pad", "K", "D", "P"),
     "singlehop": ("B", "n", "H_pad", "K", "Jtot"),
 }
 
 
 def kernel_abstract_inputs(
     kernel: str, *, B: int = 2, n: int = 8, H_pad: int | None = None,
-    ns: int | None = None, K: int | None = None, J: int | None = None,
+    ns: int | None = None, K: int | None = None, D: int | None = None,
     P: int | None = None, Jtot: int | None = None,
 ) -> tuple:
     """Abstract input specs (``jax.ShapeDtypeStruct``) for a cached kernel.
@@ -2673,9 +2672,9 @@ def kernel_abstract_inputs(
 
     Dimensions: ``B`` cases, ``n`` nodes, ``H_pad`` padded horizon, ``ns``
     capacity-LUT rows (sum of per-case ``n_slots``; any positive value is
-    shape-valid), ``K`` padded arrivals per slot, ``(P, J)`` two-hop
-    support plans x padded support size, ``Jtot`` total padded circuit
-    columns of the single-hop plan.
+    shape-valid), ``K`` padded arrivals per slot, ``(P, D)`` two-hop
+    support plans x slots per row (largest circuit degree), ``Jtot``
+    total padded circuit columns of the single-hop plan.
     """
     import jax
     import jax.numpy as jnp
@@ -2685,7 +2684,7 @@ def kernel_abstract_inputs(
     H_pad = _PAD_H if H_pad is None else int(H_pad)
     ns = B * n if ns is None else int(ns)
     K = _PAD_K if K is None else int(K)
-    J = _PAD_J if J is None else int(J)
+    D = 2 if D is None else int(D)
     P = 1 if P is None else int(P)
     Jtot = B * _pad_to(n, _PAD_J) if Jtot is None else int(Jtot)
     S = jax.ShapeDtypeStruct
@@ -2699,9 +2698,9 @@ def kernel_abstract_inputs(
     if kernel in ("twohop_dense", "twohop_fct"):
         return (caps_flat, cap_idx, apos, asz, live, direct)
     if kernel == "twohop_sparse":
-        return (caps_flat, cap_idx, apos, asz, live, S((H_pad,), i32),
-                S((P, J), i32), S((P, J), i32), S((P, J), i32),
-                S((P, J), jnp.bool_), direct)
+        table = (P, D, B * n)
+        return (apos, asz, live, S((H_pad,), i32), S(table, i32),
+                S(table, f32), S(table, i32), direct)
     # singlehop
     return (S((B * n * n,), f32), S((H_pad, K), i32), S((H_pad, K), f32),
             S((H_pad, Jtot), i32), S((H_pad, Jtot), f32))
@@ -2719,14 +2718,12 @@ def jax_kernels() -> dict:
     return _jax_fns()
 
 # Dense (einsum over the full (B, n, n) relay-bucket matrix) vs sparse
-# (padded circuit-support gathers + segment_sum) two-hop kernel crossover,
-# picked by n like ``round_matrices`` picks its batching: the dense step's
-# O(n^3) offload einsum lowers to a batched matmul and beats the sparse
-# step's O(n^2 d_hat) scalarized gather/scatter constants until n is large
-# (benchmarks/fct_bench.py ``twohop_table`` on the 2-core CI CPU: dense
-# ~1.6x ahead at n = 128, ~par at 256, behind from n ~ 384 on).  That
-# table is a CPU's; the TPU benchmark has a cell on each side of this
-# value (PERF.md section 4), and a move of the crossover is judged on both.
+# (fixed-degree circuit-support rows, one spray gather) two-hop kernel
+# crossover, picked by n like ``round_matrices`` picks its batching: the
+# dense step's O(n^3) offload einsum lowers to a batched matmul and beats
+# the sparse step's O(n^2 D) passes until n is large.  The TPU benchmark
+# has a cell on each side of this value (PERF.md section 4), and a move
+# of the crossover is judged on both.
 _TWOHOP_DENSE_MAX_N = 256
 
 _JEPS = 1e-12
@@ -2809,67 +2806,82 @@ def _jax_fns() -> dict:
             (cap_idx, apos, asz, live))
         return out, carry
 
-    def twohop_sparse(caps_flat, cap_idx, apos, asz, live, plan_idx,
-                      p_row, p_v, p_b, p_valid, direct):
+    def twohop_sparse(apos, asz, live, plan_idx, p_v, p_cap, p_in, direct):
+        # Fixed-degree support layout over rows r = (case b, node u): a
+        # plan gives each row D out-slots k, the destination p_v[k, r] and
+        # capacity p_cap[k, r] of one circuit out of u (capacity 0 in an
+        # unused slot), and each row (b, v) D in-slots naming the circuits
+        # into v by their flat out-slot k * B n + source row (B n D where
+        # unused).  Drain and direct hop read and write a row through a
+        # one-hot over its n columns, so the step holds no per-circuit
+        # gather or scatter: only the arrivals' scatter and the spray's one
+        # gather of B n D rows.
         _JAX_TRACES["twohop_sparse"] += 1
-        B, n = cap_idx.shape[1], caps_flat.shape[1]
+        B = live.shape[1]
+        D, BN = p_v.shape[1], p_v.shape[2]
+        n = BN // B
+        cols = jnp.arange(n, dtype=jnp.int32)
+        own = cols[None, :] == (jnp.arange(BN, dtype=jnp.int32) % n)[:, None]
+        direct_r = jnp.repeat(direct.reshape(B), n)
 
         def step(carry, inp):
-            # RS[(b, at), dst]: row-major bucket totals, so the drain reads
-            # and the offload fill both land on contiguous rows.  Padded
-            # support entries carry valid=False -> zero capacity -> every
-            # transfer below is an exact add-zero for them.
+            # voq[(b, src), dst] and RS[(b, at), dst]: row-major totals
             voq, RS = carry
-            cidx, pos, sz, lv, pi = inp
+            pos, sz, lv, pi = inp
             voq = voq.at[pos[:, 0], pos[:, 1], pos[:, 2]].add(sz)
-            cap3 = (caps_flat[cidx] * lv[:, None, None]).reshape(B * n, n)
-            row, v, b, valid = p_row[pi], p_v[pi], p_b[pi], p_valid[pi]
-            bv = b * n + v
+            voq3 = voq.reshape(BN, n)
+            v_out, cap, p_in_s = [
+                jax.lax.dynamic_index_in_dim(t, pi, keepdims=False)
+                for t in (p_v, p_cap, p_in)]
+            cap = cap * jnp.repeat(lv, n)
+            hot = cols == v_out[:, :, None]                  # (D, BN, n)
+
+            def pick(x):                    # x[r, v_out[k, r]]
+                return jnp.where(hot, x, 0.0).sum(axis=2)
+
+            def spread(y):                  # sum_k y[k, r] at (r, v_out)
+                return jnp.where(hot, y[:, :, None], 0.0).sum(axis=0)
+
             # priority 1: drain relayed bits over the support circuits
-            rs = jnp.where(valid, RS[row, v], 0.0)
-            cap_j = jnp.where(valid, cap3[row, v], 0.0)
-            send1 = jnp.minimum(rs, cap_j)
-            RS = RS.at[row, v].add(-send1)
-            cap3 = cap3.at[row, v].add(-send1)
-            second = jax.ops.segment_sum(send1, b, num_segments=B)
-            deliv = second
+            send1 = jnp.minimum(pick(RS), cap)
+            RS = RS - spread(send1)
+            cap = cap - send1
+            second = send1.reshape(D, B, n).sum(axis=(0, 2))
             # direct hop (vlb cases masked)
-            cap = cap3.reshape(B, n, n)
-            tx = jnp.minimum(voq, cap) * direct
-            voq = voq - tx
-            deliv = deliv + tx.sum(axis=(1, 2))
-            cap3 = (cap - tx).reshape(B * n, n)
-            voq3 = voq.reshape(B * n, n)
-            # offload leftover capacity, support rows only
-            leftover = cap3.sum(axis=1)
+            tx = jnp.minimum(pick(voq3), cap) * direct_r
+            voq3 = voq3 - spread(tx)
+            deliv = second + tx.reshape(D, B, n).sum(axis=(0, 2))
+            cap = cap - tx
+            # offload leftover capacity: coeff[k, r] = send_u * link share
+            leftover = cap.sum(axis=0)
             queue = voq3.sum(axis=1)
             send_u = jnp.minimum(leftover, queue)
-            lo_j = leftover[row]
-            ls = jnp.where(valid & (lo_j > _JEPS),
-                           cap3[row, v] / jnp.maximum(lo_j, _JEPS), 0.0)
-            coeff = send_u[row] * ls
-            q_j = queue[row]
-            qs = jnp.where((q_j > _JEPS)[:, None],
-                           voq3[row, :] / jnp.maximum(q_j, _JEPS)[:, None],
-                           0.0)
-            moved = coeff[:, None] * qs          # (J, n) over dst
-            dec = jax.ops.segment_sum(coeff, row, num_segments=B * n)
+            ls = jnp.where(leftover > _JEPS,
+                           cap / jnp.maximum(leftover, _JEPS), 0.0)
+            coeff = send_u * ls
+            qs = jnp.where((queue > _JEPS)[:, None],
+                           voq3 / jnp.maximum(queue, _JEPS)[:, None], 0.0)
             scale = jnp.where(queue > _JEPS,
-                              dec / jnp.maximum(queue, _JEPS), 0.0)
+                              coeff.sum(axis=0) / jnp.maximum(queue, _JEPS),
+                              0.0)
             voq3 = jnp.maximum(voq3 - voq3 * scale[:, None], 0.0)
+            # spray: circuit (k, r) moves coeff[k, r] * q_share[r, :] into
+            # relay row (b, v_out[k, r]), which gathers its in-circuits'
+            sent = (coeff[:, :, None] * qs).reshape(D * BN, n)
+            got = sent[jnp.minimum(p_in_s, D * BN - 1)]      # (D, BN, n)
+            moved = jnp.where((p_in_s < D * BN)[:, :, None], got,
+                              0.0).sum(axis=0)
             # bits whose relay node IS the destination arrive at once
-            dd = jnp.take_along_axis(moved, v[:, None], axis=1)[:, 0]
-            deliv = deliv + jax.ops.segment_sum(dd, b, num_segments=B)
-            moved = jnp.where(jnp.arange(n)[None, :] == v[:, None],
-                              0.0, moved)
-            RS = RS.at[bv, :].add(moved)         # -> bucket [(b, at v), dst]
+            deliv = deliv + jnp.where(own, moved, 0.0).reshape(
+                B, n * n).sum(axis=1)
+            RS = RS + jnp.where(own, 0.0, moved)
             return (voq3.reshape(B, n, n), RS), (deliv, second)
 
         carry, out = jax.lax.scan(
             step,
             (jnp.zeros((B, n, n), jnp.float32),  # lint: allow-dense
-             jnp.zeros((B * n, n), jnp.float32)),
-            (cap_idx, apos, asz, live, plan_idx))
+             jnp.zeros((BN, n), jnp.float32)),
+            (apos, asz, live, plan_idx))
         return out, carry
 
     def singlehop(voq0, apid, asz, p_pid, p_cap):
@@ -3377,10 +3389,10 @@ def _twohop_batch_jax(
     ledger — fct_slots are real.  Otherwise aggregate quantities only
     (utilization / delivered bits / avg_hops match the NumPy engine;
     fct_slots all inf).  ``kernel`` forces the ``"dense"`` einsum or
-    ``"sparse"`` padded-support formulation (both aggregate-only); by
-    default the crossover picks dense for n <= ``_TWOHOP_DENSE_MAX_N``.
-    The sparse kernel scans a per-period-residue circuit-support LUT built
-    by the same :class:`_SupportPlans` merge the NumPy engine uses.
+    ``"sparse"`` fixed-degree support formulation (both aggregate-only);
+    by default the crossover picks dense for n <= ``_TWOHOP_DENSE_MAX_N``.
+    The sparse kernel scans per-period-residue support tables
+    (:func:`_sparse_plan_lut`), with the capacities in them.
     """
     for m in modes:
         if m not in ("rotorlb", "vlb"):
@@ -3401,14 +3413,16 @@ def _twohop_batch_jax(
                 _jax_batch_inputs(cases, bits_per_slot, sp))
             direct = np.array([0.0 if m == "vlb" else 1.0 for m in modes],
                               dtype=np.float32).reshape(B, 1, 1)
-            lut = (_sparse_plan_lut(supports, n, B, H, H_pad, sp)
-                   if name == "twohop_sparse" else [])
-            inputs = [caps_flat, cap_idx, apos, asz, live, *lut, direct]
+            bucket = (B, n, H_pad, asz.shape[1])
+            if name == "twohop_sparse":
+                lut = _sparse_plan_lut(supports, caps_flat, cap_idx, H, sp)
+                inputs = [apos, asz, live, *lut, direct]
+                P, D, _ = lut[1].shape            # (P, D, B n) tables
+                bucket += (D, P)
+            else:
+                inputs = [caps_flat, cap_idx, apos, asz, live, direct]
             _staged(sp, *inputs)
         with span("fabric.dispatch"):
-            bucket = (B, n, H_pad, asz.shape[1])
-            if lut:
-                bucket += lut[1].shape[::-1]      # (J, P) of the plan LUT
             _record_call(name, bucket)
             out, (voq_f, relay_f) = fns[name](*inputs)
         delivered, second = _fetch((out[0], np.float64),
@@ -3429,46 +3443,93 @@ def _twohop_batch_jax(
     return results
 
 
-def _sparse_plan_lut(supports, n: int, B: int, H: int,
-                     H_pad: int, sp) -> list[np.ndarray]:
-    """The ``twohop_sparse`` kernel's circuit-support LUT: one padded plan
-    per distinct period-residue tuple (the same :class:`_SupportPlans`
-    merge the NumPy engine uses, over the per-case ``supports`` of
-    :func:`_caps_tables`) and each slot's index into it.  Returns
-    ``[plan_idx, p_row, p_v, p_b, p_valid]``.
+def _sparse_plan_lut(supports, caps_flat: np.ndarray, cap_idx: np.ndarray,
+                     H: int, sp) -> list[np.ndarray]:
+    """The ``twohop_sparse`` kernel's fixed-degree support tables: one plan
+    per distinct per-case period-residue tuple (:meth:`_SupportPlans.key`),
+    in order of first slot, and each slot's index into them.  Over rows
+    r = (case b, node u), a plan gives each row D out-slots k: the
+    destination ``p_v[k, r]`` and f32 capacity ``p_cap[k, r]`` of one
+    circuit out of u (read from ``caps_flat`` at the case's ``cap_idx``
+    row; capacity 0 in an unused slot), and each row (b, v) D in-slots
+    ``p_in[i, r]``: the flat out-slot ``k * B n + source row`` of one
+    circuit into v (``D * B n`` where unused).  D is the largest out- or
+    in-degree of a slot's support (merged circuits, so at most d_hat).
+    Returns ``[plan_idx, p_v, p_cap, p_in]``, the tables ``(P, D, B n)``
+    with plans padded to a power of two.
 
     Counts on the ``fabric.stage`` span ``sp`` the host ns spent here
-    (``lut_ns``)."""
+    (``lut_ns``), D (``plan_d``) and the share of the plans' slots that
+    hold a circuit (``plan_fill``)."""
     t = time.perf_counter_ns()
-    plans = _SupportPlans(supports, n, list(range(B)), B)
-    keys: dict[tuple, int] = {}
-    plan_idx = np.zeros(H_pad, dtype=np.int32)
-    plan_list: list[dict] = []
-    for slot in range(H):
-        key = plans.key(slot)
-        pi = keys.get(key)
-        if pi is None:
-            pi = keys[key] = len(plan_list)
-            plan_list.append(plans.plan(slot))
-        plan_idx[slot] = pi
-    J = _pad_to(max((p["J"] for p in plan_list), default=0), _PAD_J)
+    B, n = len(supports), caps_flat.shape[1]
+    BN = B * n
+    ns = np.array([len(sup) for sup in supports], dtype=np.int64)
+    res = np.arange(H)[:, None] % ns[None, :]
+    keys, first, inv = np.unique(res, axis=0, return_index=True,
+                                 return_inverse=True)
+    by_first = np.argsort(first)
+    keys = keys[by_first]
+    plan_idx = np.zeros(len(cap_idx), dtype=np.int32)
+    plan_idx[:H] = np.argsort(by_first)[inv.reshape(-1)]
+    # per distinct support: each circuit's flat (residue, node) out- and
+    # in-keys and its rank among its row's out- and its column's
+    # in-circuits
+    circ: dict[int, tuple] = {}
+    for sup in supports:
+        if id(sup) in circ:
+            continue
+        used = sup[:min(len(sup), H)]
+        cnt = np.array([len(at) for at, _ in used], dtype=np.int64)
+        s = np.repeat(np.arange(len(used)), cnt)
+        at = np.concatenate([at for at, _ in used]).astype(np.int64)
+        v = np.concatenate([v for _, v in used]).astype(np.int64)
+        ranks, deg = [], 1
+        for key in (s * n + at, s * n + v):
+            per = np.bincount(key, minlength=len(used) * n)
+            rank = np.empty(len(key), dtype=np.int64)
+            rank[np.argsort(key, kind="stable")] = _ranged_arange(per)
+            ranks.append(rank)
+            deg = max(deg, int(per.max(initial=0)))
+        circ[id(sup)] = (s, at, v, cnt, *ranks, deg)
+    D = max(c[-1] for c in circ.values())
     # pad the plan count to a power-of-two bucket: coprime period mixes
     # multiply distinct residue tuples toward lcm(periods), and an unpadded
-    # P would make every mix a fresh jit signature (the LUT itself stays
-    # bounded by H — at most one plan per slot)
-    P = 1 << (max(len(plan_list), 1) - 1).bit_length()
-    p_row = np.zeros((P, J), dtype=np.int32)
-    p_v = np.zeros((P, J), dtype=np.int32)
-    p_b = np.zeros((P, J), dtype=np.int32)
-    p_valid = np.zeros((P, J), dtype=bool)
-    for i, p in enumerate(plan_list):
-        j = p["J"]
-        p_row[i, :j] = p["row"]
-        p_v[i, :j] = p["v"]
-        p_b[i, :j] = p["b"]
-        p_valid[i, :j] = True
+    # P would make every mix a fresh jit signature (the tables stay bounded
+    # by H: at most one plan per slot)
+    Pu = len(keys)
+    P = 1 << (max(Pu, 1) - 1).bit_length()
+    p_v = np.zeros((P, D, BN), dtype=np.int32)
+    p_cap = np.zeros((P, D, BN), dtype=np.float32)
+    p_in = np.full((P, D, BN), D * BN, dtype=np.int32)
+    # per residue, node-major tables of one support on its capacity rows
+    # (cases of one schedule share them); the in-table holds the source's
+    # flat out-slot within its case, -1 where unused
+    tables: dict[tuple, tuple] = {}
+    filled = 0
+    for b, sup in enumerate(supports):
+        s, at, v, cnt, k_out, k_in, _ = circ[id(sup)]
+        key = (id(sup), cap_idx[:len(cnt), b].tobytes())
+        if key not in tables:
+            shape = (len(cnt), D, n)
+            tv, tc = np.zeros(shape, np.int32), np.zeros(shape, np.float32)
+            ti = np.full(shape, -1, np.int32)
+            out = (s * D + k_out) * n + at
+            tv.reshape(-1)[out] = v
+            tc.reshape(-1)[out] = caps_flat.reshape(-1)[
+                (cap_idx[s, b] * n + at) * n + v]
+            ti.reshape(-1)[(s * D + k_in) * n + v] = k_out * BN + at
+            tables[key] = (tv, tc, ti)
+        tv, tc, ti = tables[key]
+        rows, kb = slice(b * n, (b + 1) * n), keys[:, b]
+        p_v[:Pu, :, rows], p_cap[:Pu, :, rows] = tv[kb], tc[kb]
+        blk = ti[kb]
+        p_in[:Pu, :, rows] = np.where(blk < 0, D * BN, blk + b * n)
+        filled += int(cnt[kb].sum())
     sp.add("lut_ns", time.perf_counter_ns() - t)
-    return [plan_idx, p_row, p_v, p_b, p_valid]
+    sp.add("plan_d", D)
+    sp.add("plan_fill", filled / (Pu * BN * D))
+    return [plan_idx, p_v, p_cap, p_in]
 
 
 # ---------------------------------------------------------------------------

@@ -21,13 +21,13 @@ HBM_BYTES = 16 << 30
 
 # chip_smoke.py's buckets (its phase lines print them as kernels=...):
 # sweep n=256 (singlehop, twohop_dense), two-hop n=64 and n=512, where
-# ns = the capacity LUT rows, the sum of the cases' period slots
+# ns = the capacity LUT rows, the sum of the cases' period slots, and
+# (D, P) = twohop_sparse's slots per row and plans
 SMOKE_SIZES = {
     "singlehop": dict(B=6, n=256, H_pad=1024, K=352, Jtot=4928),
     "twohop_dense": dict(B=4, n=256, H_pad=1024, K=256, ns=256),
     "twohop_fct": dict(B=4, n=64, H_pad=1024, K=96, ns=64),
-    "twohop_sparse": dict(B=2, n=512, H_pad=384, K=320, J=4096, P=128,
-                          ns=256),
+    "twohop_sparse": dict(B=2, n=512, H_pad=384, K=320, D=4, P=128),
 }
 
 

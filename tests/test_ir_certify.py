@@ -50,6 +50,40 @@ def test_ir_carry_exponents_pin_bucketed_state():
     assert analyze_kernel("twohop_fct").carry_exponent > 2.5
 
 
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, nested jaxprs (closed or open)
+    included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_ir_sparse_step_has_no_per_circuit_gather_or_scatter():
+    """The traced ``twohop_sparse`` step holds one scatter-add, the
+    arrivals (K updates), and one gather, the spray's B n D source rows;
+    drain, direct hop and link shares index nothing per circuit."""
+    jax = pytest.importorskip("jax")
+    from repro.core.simulator import jax_kernels, kernel_abstract_inputs
+    dims = dict(B=2, n=8, K=32, D=3, P=4)
+    closed = jax.make_jaxpr(jax_kernels()["twohop_sparse"])(
+        *kernel_abstract_inputs("twohop_sparse", **dims))
+    (scan,) = [e for e in _eqns(closed.jaxpr)
+               if e.primitive.name == "scan"]
+    body = list(_eqns(scan.params["jaxpr"].jaxpr))
+    scatters = [e for e in body if "scatter" in e.primitive.name]
+    gathers = [e for e in body if e.primitive.name == "gather"]
+    assert [e.primitive.name for e in scatters] == ["scatter-add"]
+    assert scatters[0].invars[2].aval.size == dims["K"]
+    assert len(gathers) <= 1
+    for g in gathers:
+        idx = g.invars[1].aval.shape
+        assert np.prod(idx[:-1]) == dims["B"] * dims["n"] * dims["D"]
+
+
 def test_ir_dot_flops_match_analytic_form():
     pytest.importorskip("jax")
     from repro.analysis.ir import _REF_DIMS, analyze_kernel

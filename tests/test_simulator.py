@@ -394,6 +394,84 @@ def test_jax_twohop_kernels_no_retrace(assert_no_retrace):
                 _twohop_batch_jax(batch, BPS, ["rotorlb"], kernel=kernel)
 
 
+def _mixed_relay_batch():
+    """rotorlb and vlb cases at n = 13: oblivious d_hat = 3 and d_hat = 4
+    (periods 4 and 3), a Vermilion k = 3 schedule whose merged parallel
+    circuits leave rows below its degree, and horizons of 60 and 45."""
+    n = 13
+    wl = websearch_workload(n, 0.5, 60, BPS, d_hat=2, seed=5)
+    wl2 = websearch_workload(n, 0.7, 45, BPS, d_hat=2, seed=6)
+    sv = vermilion_schedule(wl.demand_matrix(), k=3, d_hat=3,
+                            recfg_frac=RECFG)
+    cases = [(oblivious_schedule(n, d_hat=3, recfg_frac=RECFG), wl),
+             (oblivious_schedule(n, d_hat=4, recfg_frac=RECFG), wl2),
+             (sv, wl), (sv, wl2)]
+    return cases, ["rotorlb", "vlb", "rotorlb", "vlb"]
+
+
+def _kernel_io(cases, modes, kernel):
+    """The arrays handed to ``twohop_<kernel>`` and what it returned."""
+    from repro.core import simulator as sim
+    fns = sim._jax_fns()
+    name = f"twohop_{kernel}"
+    real, seen = fns[name], {}
+
+    def spy(*args):
+        seen["io"] = (args, real(*args))
+        return seen["io"][1]
+
+    fns[name] = spy
+    try:
+        sim._twohop_batch_jax(cases, BPS, modes, kernel=kernel)
+    finally:
+        fns[name] = real
+    return seen["io"]
+
+
+def test_sparse_kernel_matches_dense_slot_by_slot():
+    """Per-slot delivered and second-hop bits and the final VOQ and relay
+    carries of ``twohop_sparse`` match ``twohop_dense`` on one batch.  The
+    two sum each slot's terms in another order (over a row's D slots, not
+    its n columns), so entries agree to f32 rounding: rtol 1e-5, plus
+    1e-6 of an array's largest entry for entries that are a difference of
+    near-equal terms (a drained queue's remainder)."""
+    pytest.importorskip("jax")
+    cases, modes = _mixed_relay_batch()
+    sv = cases[2][0]
+    assert any(len(at) < 13 * sv.d_hat for at, _, _ in sv.slot_circuits())
+    _, ((d_deliv, d_second), d_carry) = _kernel_io(cases, modes, "dense")
+    inputs, ((s_deliv, s_second), s_carry) = _kernel_io(cases, modes,
+                                                         "sparse")
+    assert inputs[4].shape[1] == 4                      # D: the d_hat = 4 case
+    pairs = [(d_deliv, s_deliv), (d_second, s_second),
+             *zip(d_carry, s_carry)]
+    for want, got in pairs:
+        want = np.asarray(want)
+        got = np.asarray(got).reshape(want.shape)
+        assert np.abs(want).max() > 0
+        np.testing.assert_allclose(got, want, rtol=1e-5,
+                                   atol=1e-6 * np.abs(want).max())
+    assert np.asarray(d_second)[45:, [1, 3]].sum() == 0     # past horizon
+
+
+def test_dense_kernel_inputs_are_pinned():
+    """The dense kernel's staged inputs for one mixed batch, byte for byte
+    (dtype, shape and data of each array): the sparse kernel's table
+    layout must leave the dense path's staging as it was."""
+    pytest.importorskip("jax")
+    import hashlib
+    cases, modes = _mixed_relay_batch()
+    inputs, _ = _kernel_io(cases, modes, "dense")
+    h = hashlib.sha256()
+    for a in inputs:
+        a = np.asarray(a)
+        h.update(a.dtype.str.encode())
+        h.update(repr(a.shape).encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == (
+        "e8e608acd6e82dcf30e1082c8aa24ac26a123479d95d23785cc65a6a6b67d002")
+
+
 def test_completed_frac_monotone_in_capacity():
     """More bits per slot never completes fewer flows."""
     wl = websearch_workload(8, 0.6, 400, BPS, d_hat=2, seed=2)

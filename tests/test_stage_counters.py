@@ -37,59 +37,74 @@ def _modes(cases):
 
 def _run(cases, kernel):
     """Serve ``cases`` on ``kernel``; returns the results, the stage
-    span's record and the arrays handed to the kernel."""
+    span's record, the arrays handed to the kernel and the batch's staged
+    capacity table and per-slot row index (``caps_flat``, ``cap_idx``),
+    which the sparse kernel reads only through its plan tables."""
     fns = sim._jax_fns()
     name = f"twohop_{kernel}"
     real, seen = fns[name], {}
+    real_inputs = sim._jax_batch_inputs
 
     def spy(*args):
         seen["inputs"] = [np.asarray(a) for a in args]
         return real(*args)
 
+    def staged(*args):
+        out = real_inputs(*args)
+        seen["tables"] = (out[2], out[3])
+        return out
+
     fns[name] = spy
+    sim._jax_batch_inputs = staged
     try:
         res = sim._twohop_batch_jax(cases, BPS, _modes(cases),
                                     kernel=kernel)
     finally:
         fns[name] = real
+        sim._jax_batch_inputs = real_inputs
     stage = next(r for r in reversed(tracing.records())
                  if r.name == "fabric.stage")
-    return res, stage, seen["inputs"]
+    return res, stage, seen["inputs"], seen["tables"]
 
 
 def test_periods_multiply_into_plans():
     cases = _cases()
     assert [s.n_slots for s, _ in cases] == [3, 4]
-    _, stage, inputs = _run(cases, "sparse")
+    _, stage, inputs, _ = _run(cases, "sparse")
     H = max(wl.horizon for _, wl in cases)
-    plan_idx, p_row, p_valid = inputs[5], inputs[6], inputs[9]
+    plan_idx, p_v, p_cap = inputs[3], inputs[4], inputs[5]
     assert len(np.unique(plan_idx[:H])) == 12             # lcm(3, 4)
-    assert p_row.shape[0] == 16                           # power-of-two pad
+    assert p_v.shape[0] == 16                             # power-of-two pad
     assert int(plan_idx.max()) == 11
-    # the largest merged plan: 4 and 3 shifts of 13 circuits, before the
-    # _PAD_J pad
-    assert int(p_valid.sum(axis=1).max()) == (4 + 3) * 13
-    assert p_row.shape[1] == sim._pad_to((4 + 3) * 13, sim._PAD_J)
+    # every merged plan: 4 and 3 shifts of 13 circuits, in rows of
+    # D = 4 slots (the d_hat = 4 case's degree)
+    assert p_v.shape[1:] == (4, 2 * 13)
+    assert (p_cap[:12] > 0).sum(axis=(1, 2)).tolist() == [(4 + 3) * 13] * 12
+    assert not p_cap[12:].any()
     assert stage.attrs["lut_ns"] > 0
+    assert stage.attrs["plan_d"] == 4
+    assert stage.attrs["plan_fill"] == pytest.approx((4 + 3) / (2 * 4))
 
 
 @pytest.mark.parametrize("kernel", ["dense", "sparse"])
 def test_caps_counters_on_every_two_hop_batch(kernel):
     cases = _cases()
-    _, stage, inputs = _run(cases, kernel)
-    caps_flat = inputs[0]
+    _, stage, inputs, (caps_flat, _) = _run(cases, kernel)
     assert caps_flat.dtype == np.float32
     assert caps_flat.shape == (3 + 4, 13, 13)
     assert stage.attrs["caps_ns"] > 0
     assert CAPS <= set(stage.attrs)
     if kernel == "sparse":
         assert LUT <= set(stage.attrs)
+        # the sparse kernel takes its capacities from the plan tables
+        assert not any(a.shape == caps_flat.shape for a in inputs)
     else:
         assert not LUT & set(stage.attrs)
+        assert inputs[0].tobytes() == caps_flat.tobytes()
 
 
 def test_counted_time_is_inside_the_stage_span():
-    _, stage, _ = _run(_cases(), "sparse")
+    _, stage, _, _ = _run(_cases(), "sparse")
     assert (stage.attrs["caps_ns"] + stage.attrs["lut_ns"]
             < stage.t1_ns - stage.t0_ns)
 
@@ -108,9 +123,9 @@ def _null_span(name, **attrs):
 def test_counting_leaves_inputs_and_results_bit_identical(kernel,
                                                           monkeypatch):
     cases = _cases(horizon=90, seed=7)
-    res, _, inputs = _run(cases, kernel)
+    res, _, inputs, _ = _run(cases, kernel)
     monkeypatch.setattr(sim, "span", _null_span)
-    bare, _, bare_inputs = _run(cases, kernel)
+    bare, _, bare_inputs, _ = _run(cases, kernel)
     assert len(inputs) == len(bare_inputs)
     for a, b in zip(inputs, bare_inputs):
         assert a.dtype == b.dtype and np.array_equal(a, b)
@@ -152,11 +167,18 @@ def _per_case_caps(cases):
     return (np.concatenate(caps64).astype(np.float32), offs, ns, caps64)
 
 
-def _per_case_lut(caps64, H, H_pad):
+def _per_case_idx(offs, ns, H, H_pad):
+    cap_idx = np.zeros((H_pad, len(ns)), dtype=np.int32)
+    cap_idx[:H] = offs[None, :] + (np.arange(H)[:, None] % ns[None, :])
+    return cap_idx
+
+
+def _per_case_lut(cases, H, H_pad):
     """The sparse lookup table built from one float64 table per case."""
+    caps_flat, offs, ns, caps64 = _per_case_caps(cases)
     return sim._sparse_plan_lut(
-        [[np.nonzero(c) for c in caps] for caps in caps64], N4,
-        len(caps64), H, H_pad, _Null())
+        [[np.nonzero(c) for c in caps] for caps in caps64], caps_flat,
+        _per_case_idx(offs, ns, H, H_pad), H, _Null())
 
 
 @pytest.mark.parametrize("batch", sorted(BATCHES))
@@ -164,8 +186,7 @@ def _per_case_lut(caps64, H, H_pad):
 def test_each_distinct_schedule_is_staged_once(kernel, batch):
     make, rows, tables = BATCHES[batch]
     cases = make()
-    _, stage, inputs = _run(cases, kernel)
-    caps_flat, cap_idx = inputs[0], inputs[1]
+    _, stage, _, (caps_flat, cap_idx) = _run(cases, kernel)
     assert caps_flat.dtype == np.float32
     assert caps_flat.shape == (rows, N4, N4)
     assert stage.attrs["caps_tables"] == tables
@@ -182,14 +203,13 @@ def test_each_distinct_schedule_is_staged_once(kernel, batch):
 @pytest.mark.parametrize("kernel", ["dense", "sparse"])
 def test_kernel_outputs_match_per_case_tables(kernel, batch):
     cases = BATCHES[batch][0]()
-    _, _, inputs = _run(cases, kernel)
-    caps_flat, offs, ns, caps64 = _per_case_caps(cases)
-    H = max(wl.horizon for _, wl in cases)
-    cap_idx = np.zeros_like(inputs[1])
-    cap_idx[:H] = offs[None, :] + (np.arange(H)[:, None] % ns[None, :])
-    per_case = [caps_flat, cap_idx, *inputs[2:]]
+    _, _, inputs, (_, staged_idx) = _run(cases, kernel)
+    caps_flat, offs, ns, _ = _per_case_caps(cases)
+    H, H_pad = max(wl.horizon for _, wl in cases), len(staged_idx)
     if kernel == "sparse":
-        per_case[5:10] = _per_case_lut(caps64, H, len(cap_idx))
+        per_case = [*inputs[:3], *_per_case_lut(cases, H, H_pad), inputs[-1]]
+    else:
+        per_case = [caps_flat, _per_case_idx(offs, ns, H, H_pad), *inputs[2:]]
     fn = sim._jax_fns()[f"twohop_{kernel}"]
     (delivered, second), carry = fn(*inputs)
     (delivered0, second0), carry0 = fn(*per_case)
@@ -201,12 +221,36 @@ def test_kernel_outputs_match_per_case_tables(kernel, batch):
 @pytest.mark.parametrize("batch", sorted(BATCHES))
 def test_lookup_table_matches_per_case_tables(batch):
     cases = BATCHES[batch][0]()
-    _, _, inputs = _run(cases, "sparse")
-    caps64 = _per_case_caps(cases)[3]
+    _, _, inputs, _ = _run(cases, "sparse")
     H = max(wl.horizon for _, wl in cases)
-    want = _per_case_lut(caps64, H, len(inputs[5]))
-    for got, ref in zip(inputs[5:10], want):
+    want = _per_case_lut(cases, H, len(inputs[3]))
+    assert len(want) == 4
+    for got, ref in zip(inputs[3:7], want):
         assert got.dtype == ref.dtype and np.array_equal(got, ref)
+
+
+@pytest.mark.parametrize("batch", sorted(BATCHES))
+def test_in_table_names_each_filled_out_slot_once(batch):
+    cases = BATCHES[batch][0]()
+    _, stage, inputs, _ = _run(cases, "sparse")
+    plan_idx, p_v, p_cap, p_in = inputs[3:7]
+    P, D, BN = p_v.shape
+    n = BN // len(cases)
+    H = max(wl.horizon for _, wl in cases)
+    used = len(np.unique(plan_idx[:H]))
+    filled = p_cap > 0
+    assert stage.attrs["plan_d"] == D
+    assert stage.attrs["plan_fill"] == pytest.approx(
+        filled[:used].sum() / (used * BN * D))
+    for p in range(P):
+        named = p_in[p] < D * BN
+        ids = p_in[p][named]                  # flat out-slots k * BN + row
+        # each filled out-slot once, and no unused one
+        assert np.array_equal(np.sort(ids), np.flatnonzero(filled[p]))
+        # an in-slot of row (b, v) names a circuit of case b into v
+        dest = np.nonzero(named)[1]
+        assert np.array_equal(p_v[p].reshape(-1)[ids], dest % n)
+        assert np.array_equal(ids % BN // n, dest // n)
 
 
 @pytest.mark.parametrize("kernel", [None, "dense", "sparse"])
