@@ -37,7 +37,7 @@ def readings(cell: harness.Cell, seed: int, control: bool = True) -> dict:
     ref, bad = harness.reference_outputs(cell, served, pairs)
     ref_s = time.perf_counter() - t0
     out = dict(seed=seed,
-               program=harness.compare(harness.program_outputs(served),
+               program=harness.compare(harness.program_outputs(cell, served),
                                        ref, bad),
                reference_s=ref_s, serve_s=served.t1 - served.t0)
     if control:

@@ -1,31 +1,58 @@
-"""The benchmark's cells: requests built from a configuration and a traffic
-mix, served through the program's entry point, and the outputs the check
-compares.
+"""The benchmark's cells: a configuration and a traffic mix, the request
+kind that serves the mix through the program's entry point, and the
+comparison the check makes of what it produced.
 
-A *request* is one grid handed to ``run_sweep(cases, bits_per_slot,
-backend="jax")``.  Its traffic comes from the mix's fixed pool, generated
-in set-up by :mod:`fabric_bench.gen`; each request relabels the racks and
-seeds its schedules afresh, so no two requests of a run are the same
-input.  Its schedules are built while it is served, by the program's
-public constructors, since building them is part of the user's path.
+A traffic mix (``fabric_bench/traffic/<mix>.json``) names its *request
+kind* under ``"kind"``, ``"sweep"`` when absent: the module
+``fabric_bench/kinds/<kind>.py``, found by name as metric readers and
+kernel counts are.  The request-specific half of a run is the kind's; the
+functions below of the same names hand each call to the cell's kind (the
+cell first, also for ``program_outputs``).  A kind module provides:
+
+* ``make_pool(cell)``: the mix's fixed traffic, generated in set-up, so
+  every run offers the same work;
+* ``make_request(cell, pool, seed, i)``: request ``i`` of the run seeded
+  ``seed``, a function of ``(seed, i)`` and the pool alone; its
+  ``slots(cell)`` is what it counts towards ``slot_rate``: simulated slots
+  times cases, unpadded;
+* ``serve(cell, req) -> Served``: one request through the program's entry
+  point.  ``Served.t0`` / ``t1`` are the host clock around all of it (the
+  program's spans inside them are the request's), and it opens the spans
+  the shared readers read: ``fb.request`` around the whole request (the
+  traced window, and the clock the span readers map the program's spans
+  by), ``fb.engine`` around the program's entry call (``engine_host_ms``,
+  the idle gaps) and, where it builds schedules outside that call,
+  ``fb.construct`` around them with their seconds in
+  ``Served.construct_s``.  Each of ``Served.rows`` carries
+  ``result.utilization`` (the program's ``SweepRow`` and ``AdaptiveRow``
+  both do): ``run.py`` counts a request with a non-finite one as failed;
+* ``kernel_batches(cell, req)``: per kernel batch name, the problem
+  shapes the request hands it (``fabric_bench/kernels`` count the least
+  work from these);
+* ``fct_pairs(cell, req, rng)``: what the check compares of a request,
+  drawn from ``rng``;
+* ``program_outputs(served)`` and ``reference_outputs(cell, served,
+  pairs, rnd=None) -> (outputs, bad)``: one dict per case, ``label``,
+  ``delivered``, ``hops`` and ``fct`` (None where not compared), in the
+  form :func:`compare` takes; ``rnd`` rounds the reference's state (the
+  control).  The reference imports nothing of the program and takes
+  nothing it made but what it holds to a stated guarantee (schedules).
+
+A kind may import another kind's functions and define only what it
+changes.
 """
 from __future__ import annotations
 
+import importlib
 import json
-import time
+import re
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from pathlib import Path
 
 import numpy as np
 
-from . import gen, reference
-
 ROOT = Path(__file__).resolve().parent
-SEED_MAX = 2**31 - 1
-# the Vermilion schedules' demand normalization, the one under which
-# reference.vermilion_violations holds them to Algorithm 1's guarantee
-VERMILION_NORMALIZE = "saturate"
 
 
 def load_json(path: Path) -> dict:
@@ -63,6 +90,25 @@ class Cell:
         """The cases of a request, per workload."""
         return self.traffic["systems"]
 
+    @property
+    def kind(self):
+        """The module that serves the traffic mix's requests."""
+        return load_kind(self.traffic.get("kind", "sweep"))
+
+
+def load_kind(name: str):
+    """``fabric_bench/kinds/<name>.py``; exits naming the module where
+    there is none."""
+    module = f"fabric_bench.kinds.{name}"
+    if re.fullmatch(r"\w+", name):
+        try:
+            return importlib.import_module(module)
+        except ModuleNotFoundError as e:
+            if e.name != module:
+                raise
+    raise SystemExit(f"fabric_bench: request kind {name!r} has no module "
+                     f"{module} (fabric_bench/kinds/{name}.py)")
+
 
 def load_cell(bench: dict, workload: str, root: Path) -> tuple[Cell, dict]:
     """The cell named ``workload`` and its entry in ``bench``; each of its
@@ -76,55 +122,9 @@ def load_cell(bench: dict, workload: str, root: Path) -> tuple[Cell, dict]:
     config = load_json(root / conf["file"])
     traffic = load_json(ROOT / "traffic" / f"{entry['traffic']}.json")
     limits = load_json(ROOT / "limits" / f"{workload}.json")
-    return Cell(workload, config, traffic, limits), entry
-
-
-# --- requests ---------------------------------------------------------------
-
-@dataclass
-class Request:
-    """The traffic of one request."""
-    seed: int                       # the seed of its Vermilion schedules
-    flows: list                     # gen.Flows, one per workload
-    workloads: list                 # the program's Workload of each
-    demand: list                    # average demand matrix of each
-
-    def slots(self, cell: Cell) -> int:
-        """Simulated slots times cases: the unit of ``slot_rate``."""
-        return len(cell.systems) * sum(f.horizon for f in self.flows)
-
-
-def make_pool(cell: Cell) -> list:
-    """The traffic mix's pool: entry i holds one workload per load, each
-    generated from the mix's ``pool_seeds[i]``, so every run offers the
-    same sizes and arrivals."""
-    w = cell.traffic["workload"]
-    return [[gen.websearch_workload(cell.n, load, w["horizon"],
-                                    cell.bits_per_slot, d_hat=cell.d_hat,
-                                    seed=base)
-             for load in w["loads"]]
-            for base in cell.traffic["pool_seeds"]]
-
-
-def make_request(cell: Cell, pool: list, seed: int, i: int) -> Request:
-    """Request ``i`` of the run seeded ``seed``: pool entry ``i`` mod the
-    pool's size, its racks relabelled by a permutation and its schedules
-    seeded, both drawn from ``(seed, i)``.  The work is the pool's; the
-    pairs, the demand matrices and the schedules differ from request to
-    request."""
-    from repro.core.simulator import Workload
-    rng = np.random.default_rng([seed, i])
-    s = int(rng.integers(0, SEED_MAX))
-    perm = rng.permutation(cell.n)
-    flows = [gen.Flows(src=perm[f.src], dst=perm[f.dst], size=f.size,
-                       arrival=f.arrival, n=f.n, horizon=f.horizon)
-             for f in pool[i % len(pool)]]
-    return Request(
-        seed=s, flows=flows,
-        workloads=[Workload(src=f.src, dst=f.dst, size=f.size,
-                            arrival=f.arrival, n=f.n, horizon=f.horizon)
-                   for f in flows],
-        demand=[f.demand_matrix() for f in flows])
+    cell = Cell(workload, config, traffic, limits)
+    cell.kind                       # a mix naming no kind module stops here
+    return cell, entry
 
 
 # --- serving ---------------------------------------------------------------
@@ -132,9 +132,10 @@ def make_request(cell: Cell, pool: list, seed: int, i: int) -> Request:
 @dataclass
 class Served:
     """What the timed path produced for one request, and its host times."""
-    request: Request
-    rows: list
-    schedules: dict                 # (workload index, label) -> perms
+    request: object                 # the kind's request
+    rows: list                      # what the entry point returned
+    schedules: dict                 # the program's schedules the
+                                    # reference is to serve, by case
     t0: float
     t1: float
     construct_s: float              # in the schedule constructors
@@ -149,118 +150,38 @@ def span(name: str):
         yield
 
 
-def serve(cell: Cell, req: Request) -> Served:
-    """Serve one request through the program's entry point."""
-    from repro.core import schedule as S
-    from repro.core import simulator as sim
-    n, d_hat, recfg = cell.n, cell.d_hat, cell.recfg_frac
-    schedules: dict = {}
-    t0 = time.perf_counter()
-    with span("fb.request"):
-        with span("fb.construct"):
-            cases = []
-            obl = None
-            for i, (wl, m) in enumerate(zip(req.workloads, req.demand)):
-                for s in cell.systems:
-                    spec = s["schedule"]
-                    if spec["kind"] == "oblivious":
-                        if obl is None:
-                            obl = S.oblivious_schedule(
-                                n, d_hat=d_hat, recfg_frac=recfg)
-                        sched = obl
-                    elif spec["kind"] == "vermilion":
-                        sched = S.vermilion_schedule(
-                            m, k=spec["k"], d_hat=d_hat, recfg_frac=recfg,
-                            normalize=VERMILION_NORMALIZE, seed=req.seed)
-                        schedules[(i, s["label"])] = sched.perms
-                    else:
-                        raise ValueError(
-                            f"unknown schedule kind {spec['kind']!r}")
-                    cases.append(sim.SweepCase(
-                        sched=sched, wl=wl, mode=s["mode"], label=s["label"]))
-        t1c = time.perf_counter()
-        with span("fb.engine"):
-            rows = sim.run_sweep(cases, cell.bits_per_slot, backend="jax")
-    t1 = time.perf_counter()
-    return Served(req, rows, schedules, t0, t1, t1c - t0)
+# --- the cell's request kind ------------------------------------------------
+
+def make_pool(cell: Cell) -> list:
+    return cell.kind.make_pool(cell)
 
 
-def kernel_batches(cell: Cell, req: Request) -> dict:
-    """The problem shapes each kernel batch of a request serves, one dict
-    per case: the roofline counts read these, never the padded shapes."""
-    out: dict = {"singlehop": [], "twohop": []}
-    for f in req.flows:
-        for s in cell.systems:
-            out["singlehop" if s["mode"] == "single_hop" else "twohop"].append(
-                dict(n=f.n, d_hat=cell.d_hat, horizon=f.horizon,
-                     flows=int((f.arrival < f.horizon).sum())))
-    return out
+def make_request(cell: Cell, pool: list, seed: int, i: int):
+    return cell.kind.make_request(cell, pool, seed, i)
 
 
-# --- outputs and the reference ---------------------------------------------
-
-def fct_pairs(cell: Cell, req: Request, rng: np.random.Generator) -> list:
-    """Per workload, the (src, dst) pairs whose flows the check compares:
-    a sample drawn from ``rng`` of the pairs that carry flows, always
-    including the pair of the largest flow."""
-    want = int(cell.traffic["check"]["fct_pairs"])
-    out = []
-    for f in req.flows:
-        pid = f.src * f.n + f.dst
-        have = np.unique(pid)
-        pick = rng.choice(have, size=min(want, len(have)), replace=False)
-        pick = np.union1d(pick, [pid[int(np.argmax(f.size))]])
-        out.append(np.stack([pick // f.n, pick % f.n], axis=1))
-    return out
+def serve(cell: Cell, req) -> Served:
+    return cell.kind.serve(cell, req)
 
 
-def program_outputs(served: Served) -> list:
-    """Per case: delivered bits, mean hop count and per-flow FCTs, as the
-    program returned them."""
-    return [dict(label=r.label, delivered=r.result.delivered_bits,
-                 hops=r.result.avg_hops, fct=np.asarray(r.result.fct_slots))
-            for r in served.rows]
+def kernel_batches(cell: Cell, req) -> dict:
+    return cell.kind.kernel_batches(cell, req)
+
+
+def fct_pairs(cell: Cell, req, rng: np.random.Generator) -> list:
+    return cell.kind.fct_pairs(cell, req, rng)
+
+
+def program_outputs(cell: Cell, served: Served) -> list:
+    return cell.kind.program_outputs(served)
 
 
 def reference_outputs(cell: Cell, served: Served, pairs: list,
                       rnd=None) -> tuple[list, int]:
-    """The reference's outputs for the cases of ``served``, in the same
-    form as :func:`program_outputs`, and the number of schedules that break
-    Algorithm 1's guarantee.  FCTs are computed for the flows of ``pairs``
-    only (the rest stay NaN)."""
-    req = served.request
-    w = cell.bits_per_slot * (1.0 - cell.recfg_frac)
-    out, bad = [], 0
-    for i, f in enumerate(req.flows):
-        for s in cell.systems:
-            spec = s["schedule"]
-            if spec["kind"] == "oblivious":
-                perms = reference.oblivious_perms(f.n)
-            else:
-                perms = served.schedules[(i, s["label"])]
-                bad += reference.perm_violations(perms)
-                bad += reference.vermilion_violations(perms, req.demand[i],
-                                                      spec["k"])
-            plan = reference.Plan(perms, cell.d_hat, w, f.n)
-            o = dict(label=s["label"])
-            if s["mode"] == "single_hop":
-                d, tr = reference.serve_singlehop(f, plan, pairs[i], rnd)
-                fct = np.full(len(f.size), np.nan)
-                for j, (u, v) in enumerate(pairs[i]):
-                    idx = np.nonzero((f.src == u) & (f.dst == v))[0]
-                    fct[idx] = reference.pair_fcts(
-                        f.size[idx], f.arrival[idx], tr[:, j])
-                o.update(delivered=float(d.sum()), hops=1.0, fct=fct)
-            else:
-                d, sec = reference.serve_twohop(
-                    f, plan, s["mode"] == "rotorlb", rnd)
-                o.update(delivered=float(d.sum()),
-                         hops=1.0 + float(sec.sum()) / max(float(d.sum()),
-                                                           1e-9),
-                         fct=None)
-            out.append(o)
-    return out, bad
+    return cell.kind.reference_outputs(cell, served, pairs, rnd)
 
+
+# --- the check ---------------------------------------------------------------
 
 def compare(prog: list, ref: list, bad: int) -> dict:
     """The numbers the check holds to its limits:

@@ -4,10 +4,10 @@ above ``_TWOHOP_DENSE_MAX_N`` racks.
 
 It solves the same problem as ``twohop_dense``, so its least work is the
 same count, imported from there: the roofline reads the work the problem
-needs whatever implements it.  The support lookup table, its padding to
-``_PAD_J`` entries and a power-of-two plan count, and the dense capacity
-table the kernel gathers from are the implementation's, not the
-problem's.
+needs whatever implements it.  The fixed-degree plan tables (per plan,
+each row's D out-circuits with their destinations and capacities and its
+D in-circuits), their padding to D slots a row and to a power-of-two
+plan count are the implementation's, not the problem's.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Host milliseconds per request building the two-hop kernels' periodic
-capacity table (each case's ``capacity_per_slot``, their concatenation and
-the float32 cast): the ``caps_ns`` counter of the program's
-``fabric.stage`` spans, part of ``stage_ms``."""
+capacity table (the cases grouped by schedule content, one float32 table
+and one circuit support per distinct schedule): the ``caps_ns`` counter
+of the program's ``fabric.stage`` spans, part of ``stage_ms``."""
 
 from fabric_bench import spans
 

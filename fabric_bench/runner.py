@@ -103,7 +103,7 @@ def check(cell: harness.Cell, served: list, seed: int) -> dict:
     rng = np.random.default_rng([seed, 1])
     s = served[int(rng.integers(len(served)))]
     pairs = harness.fct_pairs(cell, s.request, rng)
-    prog = harness.program_outputs(s)
+    prog = harness.program_outputs(cell, s)
     ref, bad = harness.reference_outputs(cell, s, pairs)
     numbers = harness.compare(prog, ref, bad)
     return {k: {"value": float(v), "limit": float(cell.limits[k])}
